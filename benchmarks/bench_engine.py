@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, smoke_variant
 from repro.core.types import SLO
 from repro.kernels import ops as kops
@@ -294,6 +295,7 @@ def bench_fused_launch(cfg, params) -> dict:
 
 
 def main(out_path: str = "BENCH_engine.json"):
+    enable_compile_cache()
     cfg = smoke_variant(get_config("granite-3-8b"))
     params = build_model(cfg).init(jax.random.PRNGKey(0))
     results = [bench_layout(cfg, params, paged) for paged in (False, True)]

@@ -30,6 +30,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.common import profiles, testbed_i
+from repro.compile_cache import enable_compile_cache
 from repro.fleet.controller import FleetPolicy
 from repro.serving.simulation import ServerlessSim
 from repro.workloads.applications import APPLICATIONS
@@ -130,6 +131,7 @@ def run_real() -> dict:
 
 
 def main():
+    enable_compile_cache()
     out = sys.argv[1] if len(sys.argv) > 1 and not sys.argv[1].startswith(
         "--") else "BENCH_fleet.json"
     t0 = time.time()

@@ -193,6 +193,8 @@ def run_restore(params) -> dict:
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out = sys.argv[1] if len(sys.argv) > 1 else "BENCH_router.json"
     import jax
     from repro.models import build_model

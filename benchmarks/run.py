@@ -1,5 +1,6 @@
 """Benchmark runner — one section per paper table/figure.
-Prints ``name,us_per_call,derived`` CSV rows.
+Prints ``name,us_per_call,derived`` CSV rows. Every section runs even
+when an earlier one raised; the exit status is 1 if any section raised.
 
     PYTHONPATH=src python -m benchmarks.run [--only fig8,fig13] [--fast]
 """
@@ -11,9 +12,11 @@ import sys
 import time
 
 from benchmarks.common import Bench
+from repro.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     ap.add_argument("--fast", action="store_true",
@@ -42,6 +45,7 @@ def main() -> None:
     }
     only = [s for s in args.only.split(",") if s]
     bench = Bench()
+    failed = []
     for name, fn in sections.items():
         if only and name not in only:
             continue
@@ -50,11 +54,14 @@ def main() -> None:
             fn(bench)
         except Exception as e:  # noqa: BLE001
             bench.add(f"{name}/ERROR", 0.0, repr(e)[:120])
+            failed.append(name)
             import traceback
             traceback.print_exc(file=sys.stderr)
         print(f"# section {name} done in {time.time()-t0:.1f}s",
               file=sys.stderr)
     bench.emit()
+    if failed:
+        sys.exit(f"sections failed: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -12,6 +12,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, smoke_variant
 from repro.models import build_model
 from repro.serving.api import SamplingParams
@@ -19,6 +20,7 @@ from repro.serving.endpoint import ServingEndpoint
 from repro.serving.engine import Engine
 from repro.serving.migration import gather_stage_caches
 
+enable_compile_cache()
 cfg = smoke_variant(get_config("jamba-v0.1-52b"))
 model = build_model(cfg)
 params = model.init(jax.random.PRNGKey(0))

@@ -9,6 +9,7 @@ client never sees the pipeline group dissolve.
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, smoke_variant
 from repro.core import GB, Gbps, ModelProfile, ServerSpec, SLO, TimingProfile
 from repro.models import build_model
@@ -16,6 +17,7 @@ from repro.serving.api import SamplingParams
 from repro.serving.endpoint import ServerlessFrontend, ServingEndpoint
 from repro.serving.engine import Engine
 
+enable_compile_cache()
 cfg = smoke_variant(get_config("granite-3-8b"))
 params = build_model(cfg).init(jax.random.PRNGKey(0))
 

@@ -16,6 +16,7 @@ import tempfile
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, smoke_variant
 from repro.core import GB, ModelProfile, SLO, ServerSpec, TimingProfile
 from repro.core.coldstart import OverlapFlags
@@ -25,6 +26,7 @@ from repro.serving.endpoint import ServerlessFrontend, ServingEndpoint
 from repro.serving.engine import Engine
 from repro.store import assert_within, crosscheck_stages
 
+enable_compile_cache()
 cfg = smoke_variant(get_config("granite-3-8b"))
 params = build_model(cfg).init(jax.random.PRNGKey(0))
 

@@ -10,12 +10,14 @@ reasons and step-metrics at the end.
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, smoke_variant
 from repro.models import build_model
 from repro.serving.api import SamplingParams
 from repro.serving.endpoint import ServingEndpoint
 from repro.serving.engine import Engine
 
+enable_compile_cache()
 cfg = smoke_variant(get_config("granite-3-8b"))
 params = build_model(cfg).init(jax.random.PRNGKey(0))
 ep = ServingEndpoint(Engine(cfg, [params], max_batch=4, max_seq=64))

@@ -14,6 +14,7 @@ import time
 import jax
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ModelConfig
 from repro.models import build_model
 from repro.training import optimizer as opt
@@ -30,6 +31,7 @@ def small_config():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

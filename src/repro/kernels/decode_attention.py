@@ -9,7 +9,12 @@ Two variants share the online-softmax inner loop:
   ``(N, bs, Hkv, hd)`` addressed through a per-sequence block table
   ``(B, nb)``. The table is a scalar-prefetch operand so the K/V BlockSpec
   index maps gather the right page for each (sequence, step) before the
-  kernel body runs.
+  kernel body runs. Each grid step loads one page with *every* KV head —
+  a ``(1, bs, Hkv, hd)`` block, whose last two dims equal the pool's, as
+  the TPU tiling requires — and the body walks the heads.
+
+``kv_len`` rides as a scalar-prefetch (SMEM) operand in both: a ``(1, 1)``
+VMEM block over a ``(B, 1)`` array is not a legal TPU block shape.
 """
 
 from __future__ import annotations
@@ -23,30 +28,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 NEG_INF = -1e30
 
 
-def _softmax_step(q, k, v, kpos, valid_len, m_scr, l_scr, acc_scr,
+def _softmax_step(q, k, v, kpos, valid_len, m_scr, l_scr, acc_scr, h: int,
                   scale: float):
-    """One online-softmax accumulation over a KV tile, shared by the
-    contiguous and paged kernels. q (G,hd); k,v (kb,hd); kpos (1,kb)
-    absolute token positions of the tile. Fully-masked tiles (ragged
-    tails, kv_len==0 rows) contribute exactly zero."""
+    """One online-softmax accumulation over a KV tile into the softmax
+    state of head ``h`` (scratch index), shared by the contiguous, paged
+    and ragged kernels. q (R,hd); k,v (kb,hd); kpos (1,kb) absolute token
+    positions of the tile; valid_len a scalar or (R,1). Fully-masked
+    tiles (ragged tails, kv_len==0 rows) contribute exactly zero."""
     mask = kpos < valid_len
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    s = jnp.where(mask, s, NEG_INF)                   # (G, kb)
+    s = jnp.where(mask, s, NEG_INF)                   # (R, kb)
 
-    m_prev = m_scr[...]
+    m_prev = m_scr[h]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+    l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
+    acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    m_scr[h] = m_new
 
 
 def _softmax_init(m_scr, l_scr, acc_scr):
@@ -55,13 +59,20 @@ def _softmax_init(m_scr, l_scr, acc_scr):
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def _softmax_finish(o_ref, m_scr, l_scr, acc_scr):
-    denom = jnp.maximum(l_scr[...], 1e-30)
-    o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+def _softmax_out(l_scr, acc_scr, h: int):
+    return acc_scr[h] / jnp.maximum(l_scr[h], 1e-30)
+
+
+def _softmax_scratch(n_heads: int, rows: int, hd: int):
+    """Per-head (m, l, acc) online-softmax state in VMEM."""
+    return [pltpu.VMEM((n_heads, rows, 1), jnp.float32),
+            pltpu.VMEM((n_heads, rows, 1), jnp.float32),
+            pltpu.VMEM((n_heads, rows, hd), jnp.float32)]
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             scale: float, kv_block: int):
+    bi = pl.program_id(0)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -74,12 +85,12 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     v = v_ref[0, 0].astype(jnp.float32)
     kpos = ik * kv_block + jax.lax.broadcasted_iota(
         jnp.int32, (1, kv_block), 1)                  # (1, kb)
-    _softmax_step(q, k, v, kpos, len_ref[0, 0], m_scr, l_scr, acc_scr,
+    _softmax_step(q, k, v, kpos, len_ref[bi], m_scr, l_scr, acc_scr, 0,
                   scale)
 
     @pl.when(ik == nk - 1)
     def _finish():
-        _softmax_finish(o_ref, m_scr, l_scr, acc_scr)
+        o_ref[0, 0] = _softmax_out(l_scr, acc_scr, 0).astype(o_ref.dtype)
 
 
 def decode_attention(q, k_cache, v_cache, kv_len, *,
@@ -99,29 +110,27 @@ def decode_attention(q, k_cache, v_cache, kv_len, *,
                  ).transpose(0, 2, 1, 3)              # (B,Hkv,S,hd)
     vt = jnp.pad(v_cache, ((0, 0), (0, s_p - s), (0, 0), (0, 0))
                  ).transpose(0, 2, 1, 3)
-    lens = kv_len.astype(jnp.int32).reshape(b, 1)
+    lens = kv_len.astype(jnp.int32).reshape(b)
 
-    grid = (b, hkv, s_p // kb)
     kernel = functools.partial(_kernel, scale=scale, kv_block=kb)
-
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, hkv, s_p // kb),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bi, h, ik: (bi, 0)),
-            pl.BlockSpec((1, 1, group, hd), lambda bi, h, ik: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, kb, hd), lambda bi, h, ik: (bi, h, ik, 0)),
-            pl.BlockSpec((1, 1, kb, hd), lambda bi, h, ik: (bi, h, ik, 0)),
+            pl.BlockSpec((1, 1, group, hd),
+                         lambda bi, h, ik, ln: (bi, h, 0, 0)),
+            pl.BlockSpec((1, 1, kb, hd), lambda bi, h, ik, ln: (bi, h, ik, 0)),
+            pl.BlockSpec((1, 1, kb, hd), lambda bi, h, ik, ln: (bi, h, ik, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, group, hd),
-                               lambda bi, h, ik: (bi, h, 0, 0)),
+                               lambda bi, h, ik, ln: (bi, h, 0, 0)),
+        scratch_shapes=_softmax_scratch(1, group, hd),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, hd), jnp.float32),
-        ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lens, qg, kt, vt)
@@ -131,24 +140,27 @@ def decode_attention(q, k_cache, v_cache, kv_len, *,
 
 def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, scale: float, page_size: int):
-    ib = pl.program_id(2)
-    nb = pl.num_programs(2)
+    bi = pl.program_id(0)
+    ib = pl.program_id(1)
+    nb = pl.num_programs(1)
 
     @pl.when(ib == 0)
     def _init():
         _softmax_init(m_scr, l_scr, acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)               # (G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)            # (bs, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
     kpos = ib * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)                 # (1, bs)
-    _softmax_step(q, k, v, kpos, len_ref[0, 0], m_scr, l_scr, acc_scr,
-                  scale)
+    valid = len_ref[bi]
+    for h in range(q_ref.shape[1]):                   # every KV head
+        q = q_ref[0, h].astype(jnp.float32)           # (G, hd)
+        k = k_ref[0, :, h, :].astype(jnp.float32)     # (bs, hd)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        _softmax_step(q, k, v, kpos, valid, m_scr, l_scr, acc_scr, h, scale)
 
     @pl.when(ib == nb - 1)
     def _finish():
-        _softmax_finish(o_ref, m_scr, l_scr, acc_scr)
+        for h in range(q_ref.shape[1]):
+            o_ref[0, h] = _softmax_out(l_scr, acc_scr, h).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_len, *,
@@ -169,40 +181,32 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_len, *,
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
 
     qg = q[:, 0].reshape(b, hkv, group, hd)           # (B,Hkv,G,hd)
-    lens = kv_len.astype(jnp.int32).reshape(b, 1)
+    lens = kv_len.astype(jnp.int32).reshape(b)
     tables = block_tables.astype(jnp.int32)
 
-    grid = (b, hkv, nb)
     kernel = functools.partial(_paged_kernel, scale=scale,
                                page_size=page_size)
-
+    page = lambda bi, ib, bt, ln: (bt[bi, ib], 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
+        num_scalar_prefetch=2,
+        grid=(b, nb),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bi, h, ib, bt: (bi, 0)),
-            pl.BlockSpec((1, 1, group, hd),
-                         lambda bi, h, ib, bt: (bi, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda bi, h, ib, bt: (bt[bi, ib], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda bi, h, ib, bt: (bt[bi, ib], 0, h, 0)),
+            pl.BlockSpec((1, hkv, group, hd),
+                         lambda bi, ib, bt, ln: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, page_size, hkv, hd), page),
+            pl.BlockSpec((1, page_size, hkv, hd), page),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, hd),
-                               lambda bi, h, ib, bt: (bi, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, hd), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, hkv, group, hd),
+                               lambda bi, ib, bt, ln: (bi, 0, 0, 0)),
+        scratch_shapes=_softmax_scratch(hkv, group, hd),
     )
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, hd), q.dtype),
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, lens, qg, k_pages, v_pages)
 

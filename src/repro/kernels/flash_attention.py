@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 NEG_INF = -1e30
 
 
@@ -111,7 +109,7 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
             pltpu.VMEM((qb, 1), jnp.float32),
             pltpu.VMEM((qb, hd), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -66,22 +66,36 @@ def attention_mode() -> str:
     return _ATTN_MODE
 
 
+BACKENDS = ("pallas", "interpret", "ref")
+
+
+def _check_backend(name: str) -> str:
+    if name not in BACKENDS:
+        raise ValueError(f"kernel backend {name!r}: want {'|'.join(BACKENDS)}")
+    if name != "pallas" and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"kernel backend {name!r} on a TPU would run the model without "
+            "its Pallas kernels; unset REPRO_KERNEL_BACKEND or use 'pallas'")
+    return name
+
+
 def backend() -> str:
+    """The process's kernel backend: ``REPRO_KERNEL_BACKEND`` if set, else
+    ``pallas`` on a TPU and ``ref`` elsewhere. A TPU accepts only
+    ``pallas``."""
     global _BACKEND
     if _BACKEND is None:
         forced = os.environ.get("REPRO_KERNEL_BACKEND")
         if forced:
-            _BACKEND = forced
+            _BACKEND = _check_backend(forced)
         else:
-            plat = jax.default_backend()
-            _BACKEND = "pallas" if plat == "tpu" else "ref"
+            _BACKEND = "pallas" if jax.default_backend() == "tpu" else "ref"
     return _BACKEND
 
 
 def set_backend(name: str):
     global _BACKEND
-    assert name in ("pallas", "interpret", "ref")
-    _BACKEND = name
+    _BACKEND = _check_backend(name)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,

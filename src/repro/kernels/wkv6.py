@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
             y_ref, sT_ref, state_scr, *, chunk: int):
@@ -109,7 +107,7 @@ def wkv6(r, k, v, w, u, initial_state=None, *, chunk: int = 64,
             jax.ShapeDtypeStruct((bh, n, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rr, kk, vv, ww, uu, s0)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and dump roofline inputs.
 
@@ -9,22 +6,23 @@ production meshes and dump roofline inputs.
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
 
 Results (memory analysis, cost analysis, collective bytes) are appended as
-JSON lines under experiments/dryrun/.
+JSON lines under experiments/dryrun/. ``main`` asks XLA for 512 host
+devices before JAX starts; importing this module changes nothing.
 """
 
-import argparse          # noqa: E402
-import json              # noqa: E402
-import time              # noqa: E402
-import traceback         # noqa: E402
+import argparse
+import json
+import os
+import time
+import traceback
 
-import jax               # noqa: E402
+import jax
 
-from repro.configs import (SHAPES, applicable_shapes, get_config,  # noqa: E402
-                           list_configs)
-from repro.distributed.sharding import use_mesh                     # noqa: E402
-from repro.launch.mesh import make_production_mesh                  # noqa: E402
-from repro.launch.specs import make_cell, rules_for                 # noqa: E402
-from repro.roofline import analysis                                 # noqa: E402
+from repro.configs import SHAPES, applicable_shapes, get_config, list_configs
+from repro.distributed.sharding import use_mesh
+from repro.launch.mesh import make_production_mesh
+from repro.launch.specs import make_cell, rules_for
+from repro.roofline import analysis
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
@@ -106,6 +104,7 @@ def cells(multi_pod: bool):
 
 
 def main():
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

@@ -120,3 +120,17 @@ def test_flash_blocked_grad_matches_naive():
     g1 = jax.grad(f_blocked)(q)
     g2 = jax.grad(f_naive)(q)
     assert float(jnp.max(jnp.abs(g1 - g2))) < 5e-4
+
+
+@pytest.mark.parametrize("forced", ["ref", "interpret"])
+def test_backend_refuses_non_pallas_on_tpu(forced, monkeypatch):
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_BACKEND", None)
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", forced)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="without its Pallas kernels"):
+        ops.backend()
+    with pytest.raises(RuntimeError, match="without its Pallas kernels"):
+        ops.set_backend(forced)
+    monkeypatch.delenv("REPRO_KERNEL_BACKEND")
+    assert ops.backend() == "pallas"
