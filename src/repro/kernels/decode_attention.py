@@ -133,6 +133,7 @@ def decode_attention(q, k_cache, v_cache, kv_len, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "decode_attention"},
     )(lens, qg, kt, vt)
 
     return out.reshape(b, 1, hq, hd)
@@ -208,6 +209,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_len, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "paged_decode_attention"},
     )(tables, lens, qg, k_pages, v_pages)
 
     return out.reshape(b, 1, hq, hd)

@@ -113,6 +113,7 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "flash_attention"},
     )(qt, kt, vt)
 
     return out.transpose(0, 2, 1, 3)[:, :sq]

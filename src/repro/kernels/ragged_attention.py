@@ -144,6 +144,7 @@ def ragged_paged_attention(q, k_pages, v_pages, tables, row, pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "ragged_paged_attention"},
     )(row32, tables32, pos32, *operands)
 
     return out.reshape(nt, hkv, tile_q, group, hd).transpose(

@@ -110,6 +110,7 @@ def wkv6(r, k, v, w, u, initial_state=None, *, chunk: int = 64,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "wkv6"},
     )(rr, kk, vv, ww, uu, s0)
 
     y = y.reshape(b, h, t_p, n).transpose(0, 2, 1, 3)[:, :t]

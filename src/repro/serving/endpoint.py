@@ -14,7 +14,9 @@ registers model profiles with the ``CentralController``, and on a cold
 start runs Alg. 1 (``plan_cold_start``), *streams* each stage's parameter
 slice out of the deployment's ``ModelStore`` (repro/store/) with the
 ``StreamedStageLoader``, and hands back a live endpoint whose
-``cold_start_timeline`` carries the measured per-stage spans. ``deploy``
+``cold_start_timeline`` carries the per-stage spans on the fetch
+schedule's simulated clock; wall-clock time is in the ``frontend.*``,
+``loader.stage`` and ``engine.*`` spans (``repro.spans``). ``deploy``
 without a ``store_dir`` keeps the old in-memory behaviour as a
 ``ModelStore.from_params`` tier — same bytes, same engine outputs, but
 the load path is the real one either way. Consolidation's full-model
@@ -28,6 +30,7 @@ import itertools
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Sequence, Union
 
+from repro import spans
 from repro.configs.base import ModelConfig
 from repro.core.coldstart import OverlapFlags
 from repro.core.controller import CentralController
@@ -50,10 +53,11 @@ class ServingEndpoint:
                  cold_start_timeline: Optional[ColdStartReport] = None):
         self._engine = engine
         self.scheme = scheme              # Alg.1 plan that built us, if any
-        # measured per-stage cold-start spans (store-backed cold starts)
+        # per-stage cold-start spans on the fetch schedule's simulated
+        # clock (store-backed cold starts)
         self.cold_start_timeline = cold_start_timeline
-        # measured KV-migration transfer of the last consolidation, if the
-        # frontend drove it (ServerlessFrontend.consolidate)
+        # KV-migration transfer of the last consolidation on the simulated
+        # clock, if the frontend drove it (ServerlessFrontend.consolidate)
         self.last_migration_flow: Optional[FetchFlow] = None
 
     # -------------------------------------------------------- delegation
@@ -159,7 +163,9 @@ class PendingColdStart:
     """A cold start whose stage fetch flows are admitted on the shared
     schedule but not yet resolved. ``finish()`` streams the stage
     parameters and builds the live endpoint; everything begun before the
-    first ``finish`` contends on the simulated NICs."""
+    first ``finish`` contends on the simulated NICs. The report it builds
+    runs on that simulated clock; the wall-clock time of ``finish`` is
+    its ``loader.stage`` and ``engine.init`` spans."""
 
     def __init__(self, name: str, dep: "_Deployment", scheme,
                  flags: OverlapFlags, pending, engine_kw: dict):
@@ -203,7 +209,7 @@ class ServerlessFrontend:
         self.schedule = FetchSchedule(self.controller.tracker)
         self._deployed: Dict[str, _Deployment] = {}
         self._fid = itertools.count()
-        # measured record of the last full_params store fetch (§6.2)
+        # simulated-clock record of the last full_params fetch (§6.2)
         self.last_full_fetch: Optional[StageLoadRecord] = None
 
     def deploy(self, cfg: ModelConfig, params: Optional[dict],
@@ -220,20 +226,23 @@ class ServerlessFrontend:
         ``params=None`` is the *cold deploy* path: the model was never
         resident in this process — its bytes already live in an existing
         on-disk store (``store_dir``) or an explicit ``store``, and the
-        first cold start is the first time any of them are read."""
+        first cold start is the first time any of them are read. One
+        ``frontend.deploy`` span, counting the store's ``bytes``."""
         self.controller.register_model(profile)
-        model = build_model(cfg) if params is not None else None
-        if store is None:
-            if params is None:
-                if store_dir is None:
-                    raise ValueError(
-                        "cold deploy (params=None) needs an existing store: "
-                        "pass store= or store_dir=")
-                store = ModelStore.open(store_dir)
-            elif store_dir is not None:
-                store = ModelStore.save(store_dir, model, params)
-            else:
-                store = ModelStore.from_params(model, params)
+        with spans.span("frontend.deploy", model=profile.name):
+            model = build_model(cfg) if params is not None else None
+            if store is None:
+                if params is None:
+                    if store_dir is None:
+                        raise ValueError(
+                            "cold deploy (params=None) needs an existing "
+                            "store: pass store= or store_dir=")
+                    store = ModelStore.open(store_dir)
+                elif store_dir is not None:
+                    store = ModelStore.save(store_dir, model, params)
+                else:
+                    store = ModelStore.from_params(model, params)
+            spans.count("bytes", store.total_bytes)
         self._deployed[profile.name] = _Deployment(cfg, model, store,
                                                    profile)
         return store
@@ -281,34 +290,37 @@ class ServerlessFrontend:
         distributed model fetches from its fast tier; an *unseeded*
         scheme falls back to ``fallback_tier`` (the fleet passes the
         store's authoritative/slowest tier; None keeps the store's
-        default fastest tier, the single-model behaviour)."""
+        default fastest tier, the single-model behaviour). One
+        ``frontend.plan`` span."""
         dep = self._deployed[name]
-        scheme = self.controller.plan_cold_start(name, free_hbm, now,
-                                                 force_s=force_s,
-                                                 prefer=prefer)
-        n_stages = min(max(scheme.s, min_stages), dep.cfg.n_periods)
-        if n_stages == scheme.s:
-            servers = list(scheme.servers)
-        else:                       # min_stages overrode the plan's degree
-            pool = scheme.servers or tuple(self.servers)
-            servers = [pool[i % len(pool)] for i in range(n_stages)]
-        if tier is None:
-            placed = {self.controller.placement_tier(name, sid)
-                      for sid in servers} - {None}
-            for t in sorted(placed):
-                if dep.store.has_tier(t):
-                    tier = t
-                    break
-            else:
-                tier = fallback_tier
-        deadline = self.controller.fetch_deadline(name, scheme, now)
-        loader = self._loader(dep, flags, tier, self._load_bw(servers))
-        worker_ids = [f"{name}/f{next(self._fid)}-s{i}"
-                      for i in range(n_stages)]
-        pending = [loader.admit_stage(n_stages, i, server_id=servers[i],
-                                      worker_id=worker_ids[i], now=now,
-                                      deadline=deadline)
-                   for i in range(n_stages)]
+        with spans.span("frontend.plan", model=name):
+            scheme = self.controller.plan_cold_start(name, free_hbm, now,
+                                                     force_s=force_s,
+                                                     prefer=prefer)
+            n_stages = min(max(scheme.s, min_stages), dep.cfg.n_periods)
+            if n_stages == scheme.s:
+                servers = list(scheme.servers)
+            else:                   # min_stages overrode the plan's degree
+                pool = scheme.servers or tuple(self.servers)
+                servers = [pool[i % len(pool)] for i in range(n_stages)]
+            if tier is None:
+                placed = {self.controller.placement_tier(name, sid)
+                          for sid in servers} - {None}
+                for t in sorted(placed):
+                    if dep.store.has_tier(t):
+                        tier = t
+                        break
+                else:
+                    tier = fallback_tier
+            deadline = self.controller.fetch_deadline(name, scheme, now)
+            loader = self._loader(dep, flags, tier, self._load_bw(servers))
+            worker_ids = [f"{name}/f{next(self._fid)}-s{i}"
+                          for i in range(n_stages)]
+            pending = [loader.admit_stage(n_stages, i,
+                                          server_id=servers[i],
+                                          worker_id=worker_ids[i], now=now,
+                                          deadline=deadline)
+                       for i in range(n_stages)]
         engine_kw = dict(max_batch=max_batch, max_seq=max_seq,
                          block_size=block_size, paged=paged,
                          prefix_cache=prefix_cache,
@@ -322,34 +334,43 @@ class ServerlessFrontend:
         every stage's fetch into the shared schedule (stages landing on
         the same server contend per Alg. 2), stream each stage's
         parameters out of the store in manifest order, and return a live
-        endpoint whose ``cold_start_timeline`` is the *measured* per-stage
-        ``WorkerTimeline`` report under ``flags``.
+        endpoint whose ``cold_start_timeline`` is the per-stage
+        ``WorkerTimeline`` report under ``flags``, on the fetch schedule's
+        simulated clock. Wall-clock time is the ``frontend.cold_start``
+        span: ``frontend.plan`` (Alg. 1 and the admissions), one
+        ``loader.stage`` per stage, then ``engine.init``.
         ``prefix_cache``/``prefill_chunk``/``policy`` pass through to the
         engine (the first two need the paged layout) and survive
         consolidation. (``begin_cold_start`` + ``finish`` split the same
         operation for concurrent fleet launches.)"""
-        return self.begin_cold_start(name, **kw).finish()
+        with spans.span("frontend.cold_start", model=name) as rec:
+            pending = self.begin_cold_start(name, **kw)
+            rec.attrs["stages"] = pending.n_stages
+            return pending.finish()
 
     def full_params(self, name: str, *, now: float = 0.0,
                     server_id: Optional[str] = None,
                     tier: Optional[str] = None) -> dict:
         """The un-sliced weights, fetched through the store (the paper's
         warm-pool / object-store fill-in that consolidation's standalone
-        worker performs). The measured record of the last such fetch is
-        kept on ``last_full_fetch``."""
+        worker performs). The record of the last such fetch, on the fetch
+        schedule's simulated clock, is kept on ``last_full_fetch``; its
+        wall-clock time is the ``frontend.full_fetch`` span (``bytes``),
+        whose ``loader.stage`` child counts ``read_ns`` and ``put_ns``."""
         dep = self._deployed[name]
         sid = server_id or next(iter(self.servers), "local")
         # the consolidating worker is already warm: no container/lib/cuda
-        # stubs, just the measured fetch + load legs
+        # stubs, just the fetch + load legs
         warm = dataclasses.replace(dep.profile.timings,
                                    t_cc=0.0, t_l=0.0, t_cu=0.0)
-        loader = StreamedStageLoader(dep.store, self.schedule, warm,
-                                     OverlapFlags.all(),
-                                     load_bytes_per_s=self._load_bw([sid]),
-                                     tier=tier)
-        params, record = loader.load_stage(
-            1, 0, server_id=sid, worker_id=f"{name}/full{next(self._fid)}",
-            now=now)
+        with spans.span("frontend.full_fetch", model=name):
+            loader = StreamedStageLoader(
+                dep.store, self.schedule, warm, OverlapFlags.all(),
+                load_bytes_per_s=self._load_bw([sid]), tier=tier)
+            params, record = loader.load_stage(
+                1, 0, server_id=sid,
+                worker_id=f"{name}/full{next(self._fid)}", now=now)
+            spans.count("bytes", record.fetched_bytes)
         self.last_full_fetch = record
         return params
 
@@ -359,17 +380,22 @@ class ServerlessFrontend:
         """§6.2 scale-down, data plane included: fetch the full weights
         through the store onto the surviving worker's server, swap the
         consolidated engine in behind the endpoint handle, then account
-        the measured KV-migration transfer (``last_migration_bytes`` —
-        the exact bytes the paged gather moved) as a real flow on that
-        server's NIC (``endpoint.last_migration_flow``)."""
+        the KV-migration transfer (``last_migration_bytes`` — the exact
+        bytes the paged gather moved) as a flow on that server's simulated
+        NIC (``endpoint.last_migration_flow``). One
+        ``frontend.consolidate`` span: ``frontend.full_fetch``, then the
+        engine's ``engine.init``, ``engine.kv_gather`` and
+        ``engine.rebuild_rows``."""
         sid = endpoint.scheme.servers[0] if (
             endpoint.scheme and endpoint.scheme.servers) \
             else next(iter(self.servers), "local")
-        params = self.full_params(name, now=now, server_id=sid, tier=tier)
-        endpoint.consolidate(params)
-        moved = endpoint.last_migration_bytes
-        if moved:
-            endpoint.last_migration_flow = self.schedule.transfer(
-                sid, f"{name}/kvmig{next(self._fid)}", moved,
-                now=max(now, self.last_full_fetch.timeline.ready))
+        with spans.span("frontend.consolidate", model=name):
+            params = self.full_params(name, now=now, server_id=sid,
+                                      tier=tier)
+            endpoint.consolidate(params)
+            moved = endpoint.last_migration_bytes
+            if moved:
+                endpoint.last_migration_flow = self.schedule.transfer(
+                    sid, f"{name}/kvmig{next(self._fid)}", moved,
+                    now=max(now, self.last_full_fetch.timeline.ready))
         return endpoint

@@ -71,9 +71,11 @@ import itertools
 import math
 from typing import Iterator, List, Optional, Sequence, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
 from repro.models.attention import paged_kv_token_bytes
@@ -148,14 +150,20 @@ class Engine:
         # formula; int8 adds the per-row f32 scale/zero leaves
         kv_per_tok = paged_kv_token_bytes(cfg, kv_dtype)
         n_blocks = max_batch * (max_seq // block_size + 1)
-        self.block_mgr = BlockManager(
-            n_blocks=n_blocks, block_size=block_size,
-            bytes_per_token=max(kv_per_tok, 1), prefix_cache=prefix_cache)
-        self.scheduler = Scheduler(self.block_mgr, max_batch, policy,
-                                   prefix_cache=prefix_cache)
-        self.runner = ModelRunner(cfg, stage_params, max_batch, max_seq,
-                                  paged=paged, n_blocks=n_blocks,
-                                  block_size=block_size, kv_dtype=kv_dtype)
+        with spans.span("engine.init", workers=len(stage_params)):
+            self.block_mgr = BlockManager(
+                n_blocks=n_blocks, block_size=block_size,
+                bytes_per_token=max(kv_per_tok, 1),
+                prefix_cache=prefix_cache)
+            self.scheduler = Scheduler(self.block_mgr, max_batch, policy,
+                                       prefix_cache=prefix_cache)
+            self.runner = ModelRunner(cfg, stage_params, max_batch, max_seq,
+                                      paged=paged, n_blocks=n_blocks,
+                                      block_size=block_size,
+                                      kv_dtype=kv_dtype)
+            spans.count("pool_bytes", sum(
+                leaf.nbytes for w in self.runner.workers
+                for leaf in jax.tree.leaves(w.cache)))
         self._rid = itertools.count()
         self.finished: List[GenRequest] = []
         self.steps = 0
@@ -360,31 +368,32 @@ class Engine:
         emitted, so its final logits are discarded and decode simply
         restarts from the last emitted token."""
         req = pa.req
-        if req.prefix_embeds is not None:
-            if pa.start != 0 or pa.n != req.prompt_total:
-                raise KVInvariantError(
-                    "prefix_embeds prefill must cover the whole prompt in "
-                    f"one chunk (got [{pa.start}, {pa.start + pa.n}) of "
-                    f"{req.prompt_total})")
-            tok = req.prompt
-        else:
-            tok = req.chain()[pa.start:pa.start + pa.n]
-        h = self.runner.prefill(req.slot, tok, pa.start, pa.n,
-                                prefix_embeds=req.prefix_embeds)
-        req.prefilled = pa.start + pa.n
-        self._step_prefill_tokens += pa.n
-        self.block_mgr.commit(req.rid, req.prefilled)
-        if not req.prefill_done:
-            return
-        if not req.generated:             # first admission: emit token 0
-            req.metrics.admit_step = self.steps
-            first = sample_token(h[0, 0], req.params, 0)
-            reason = self._emit(req, first, events)
-            self._extend(req, first)
-            if reason is not None:
-                self._finish(req, reason)
-        else:                             # resume: decode re-feeds the tail
-            self._extend(req, req.generated[-1])
+        with spans.span("engine.prefill", rid=req.rid, tokens=pa.n):
+            if req.prefix_embeds is not None:
+                if pa.start != 0 or pa.n != req.prompt_total:
+                    raise KVInvariantError(
+                        "prefix_embeds prefill must cover the whole prompt "
+                        f"in one chunk (got [{pa.start}, {pa.start + pa.n}) "
+                        f"of {req.prompt_total})")
+                tok = req.prompt
+            else:
+                tok = req.chain()[pa.start:pa.start + pa.n]
+            h = self.runner.prefill(req.slot, tok, pa.start, pa.n,
+                                    prefix_embeds=req.prefix_embeds)
+            req.prefilled = pa.start + pa.n
+            self._step_prefill_tokens += pa.n
+            self.block_mgr.commit(req.rid, req.prefilled)
+            if not req.prefill_done:
+                return
+            if not req.generated:         # first admission: emit token 0
+                req.metrics.admit_step = self.steps
+                first = sample_token(h[0, 0], req.params, 0)
+                reason = self._emit(req, first, events)
+                self._extend(req, first)
+                if reason is not None:
+                    self._finish(req, reason)
+            else:                         # resume: decode re-feeds the tail
+                self._extend(req, req.generated[-1])
 
     def step(self) -> StepOutput:
         """One scheduler iteration: ask the Scheduler for ScheduleBatch
@@ -398,11 +407,22 @@ class Engine:
 
         ``fused=True`` engines route through :meth:`_step_fused`: the
         same plans, but every forward of the step collapses into (at
-        most) two fused ragged launches."""
-        if self.fused:
-            return self._step_fused()
+        most) two fused ragged launches.
+
+        Both paths record the same spans (``repro.spans``): one
+        ``engine.step`` counting ``decode_rows``, ``prefill_tokens`` and
+        ``preempted``; inside it ``engine.schedule`` per plan,
+        ``engine.prefill``, ``runner.decode``, then ``engine.sample`` and
+        ``engine.emit`` once each over the whole decode batch."""
         self._check_live()
         self.steps += 1
+        with spans.span("engine.step", step_num=self.steps):
+            out = self._step_fused() if self.fused else self._step_unfused()
+            spans.count("prefill_tokens", out.prefill_tokens)
+            spans.count("preempted", len(out.preempted))
+        return out
+
+    def _step_unfused(self) -> StepOutput:
         events: List[TokenEvent] = []
         n_done = len(self.finished)
         self._step_prefill_tokens = 0
@@ -412,41 +432,45 @@ class Engine:
                          else self.prefill_chunk)
         preempted_rids: List[int] = []
         while True:
-            plan = sched.schedule()
-            for req, slot in plan.preempted:
-                preempted_rids.append(req.rid)
-                self.runner.clear_row(slot)
-                self.runner.clear_slot(slot)
-            for req in plan.admitted:
-                self.runner.set_row(req.slot,
-                                    self.block_mgr.tables[req.rid].blocks)
-            self._apply_restores(plan.admitted)
-            self._apply_copies()
+            with spans.span("engine.schedule"):
+                plan = sched.schedule()
+                for req, slot in plan.preempted:
+                    preempted_rids.append(req.rid)
+                    self.runner.clear_row(slot)
+                    self.runner.clear_slot(slot)
+                for req in plan.admitted:
+                    self.runner.set_row(
+                        req.slot, self.block_mgr.tables[req.rid].blocks)
+                self._apply_restores(plan.admitted)
+                self._apply_copies()
             for pa in plan.prefills:
                 self._exec_prefill(pa, events)
             if plan.idle:
                 break
         reqs = list(plan.decodes)
+        spans.count("decode_rows", len(reqs))
         if reqs:
             skip = [r.slot for r in sched.running() if not r.prefill_done]
             h = self.runner.decode(reqs, skip_slots=skip)
-            greedy = None
-            if any(r.params.greedy for r in reqs):
-                greedy = np.asarray(jnp.argmax(h[:, 0], axis=-1))
-            for r in reqs:
-                if r.params.greedy:
-                    nxt = int(greedy[r.slot])
-                else:
-                    nxt = sample_token(h[r.slot, 0], r.params,
-                                       len(r.generated))
-                r.metrics.decode_steps += 1
-                reason = self._emit(r, nxt, events)
-                # the fed token's KV is now material through pos_next + 1
-                self.block_mgr.commit(
-                    r.rid, r.prompt_total + len(r.generated) - 1)
-                self._extend(r, nxt)
-                if reason is not None:
-                    self._finish(r, reason)
+            with spans.span("engine.sample"):
+                greedy = None
+                if any(r.params.greedy for r in reqs):
+                    greedy = np.asarray(jnp.argmax(h[:, 0], axis=-1))
+                nxts = [int(greedy[r.slot]) if r.params.greedy
+                        else sample_token(h[r.slot, 0], r.params,
+                                          len(r.generated))
+                        for r in reqs]
+            with spans.span("engine.emit"):
+                for r, nxt in zip(reqs, nxts):
+                    r.metrics.decode_steps += 1
+                    reason = self._emit(r, nxt, events)
+                    # the fed token's KV is now material through
+                    # pos_next + 1
+                    self.block_mgr.commit(
+                        r.rid, r.prompt_total + len(r.generated) - 1)
+                    self._extend(r, nxt)
+                    if reason is not None:
+                        self._finish(r, reason)
         return StepOutput(self.steps, tuple(events),
                           tuple(r.rid for r in self.finished[n_done:]),
                           len(self.active()), sched.num_queued(),
@@ -473,9 +497,8 @@ class Engine:
         streams are unchanged); emission order matches the legacy step
         exactly (prefill first-tokens in plan order, then decode tokens in
         ``plan.decodes`` order), so greedy token streams are bit-exact
-        with a non-fused engine."""
-        self._check_live()
-        self.steps += 1
+        with a non-fused engine. Each launch is a ``runner.decode``
+        span."""
         events: List[TokenEvent] = []
         n_done = len(self.finished)
         self._step_prefill_tokens = 0
@@ -486,23 +509,25 @@ class Engine:
         preempted_rids: List[int] = []
         pending: List[PrefillAssignment] = []
         while True:
-            plan = sched.schedule()
-            for req, slot in plan.preempted:
-                preempted_rids.append(req.rid)
-                self.runner.clear_row(slot)
-                self.runner.clear_slot(slot)
-                # a deferred chunk whose request just lost its slot and
-                # blocks must not execute: the launch would write into
-                # freed (possibly re-allocated) pages
-                pending = [pa for pa in pending if pa.req.rid != req.rid]
-            for req in plan.admitted:
-                self.runner.set_row(req.slot,
-                                    self.block_mgr.tables[req.rid].blocks)
-            self._apply_restores(plan.admitted)
-            self._apply_copies()
-            for pa in plan.prefills:
-                pa.req.prefilled = pa.start + pa.n
-                pending.append(pa)
+            with spans.span("engine.schedule"):
+                plan = sched.schedule()
+                for req, slot in plan.preempted:
+                    preempted_rids.append(req.rid)
+                    self.runner.clear_row(slot)
+                    self.runner.clear_slot(slot)
+                    # a deferred chunk whose request just lost its slot
+                    # and blocks must not execute: the launch would write
+                    # into freed (possibly re-allocated) pages
+                    pending = [pa for pa in pending
+                               if pa.req.rid != req.rid]
+                for req in plan.admitted:
+                    self.runner.set_row(
+                        req.slot, self.block_mgr.tables[req.rid].blocks)
+                self._apply_restores(plan.admitted)
+                self._apply_copies()
+                for pa in plan.prefills:
+                    pa.req.prefilled = pa.start + pa.n
+                    pending.append(pa)
             if plan.idle:
                 break
 
@@ -537,23 +562,29 @@ class Engine:
         for r in old_decodes:
             seg_of[r.rid] = len(segments)
             segments.append((r.slot, [r.generated[-1]], r.pos_next))
-        h1 = self.runner.forward_batch(segments) if segments else None
+        h1 = None
+        if segments:
+            with spans.span("runner.decode", batch=len(segments)):
+                h1 = self.runner.forward_batch(segments)
 
         # ---- prefill lifecycle effects, in plan order
-        for rid in order:
-            req = chunks[rid][0]
-            self.block_mgr.commit(req.rid, req.prefilled)
-            if not req.prefill_done:
-                continue
-            if not req.generated:         # first admission: emit token 0
-                req.metrics.admit_step = self.steps
-                first = sample_token(h1[seg_of[rid]], req.params, 0)
-                reason = self._emit(req, first, events)
-                self._extend(req, first)
-                if reason is not None:
-                    self._finish(req, reason)
-            else:                         # resume: decode re-feeds the tail
-                self._extend(req, req.generated[-1])
+        if order:
+            with spans.span("engine.prefill",
+                            tokens=self._step_prefill_tokens):
+                for rid in order:
+                    req = chunks[rid][0]
+                    self.block_mgr.commit(req.rid, req.prefilled)
+                    if not req.prefill_done:
+                        continue
+                    if not req.generated:  # first admission: emit token 0
+                        req.metrics.admit_step = self.steps
+                        first = sample_token(h1[seg_of[rid]], req.params, 0)
+                        reason = self._emit(req, first, events)
+                        self._extend(req, first)
+                        if reason is not None:
+                            self._finish(req, reason)
+                    else:                  # resume: re-feed the tail
+                        self._extend(req, req.generated[-1])
 
         # ---- launch 2: requests whose prefill completed this step decode
         # their freshly sampled / re-fed token
@@ -566,25 +597,31 @@ class Engine:
             for i, r in enumerate(new_decodes):
                 idx2[r.rid] = i
                 segs2.append((r.slot, [r.generated[-1]], r.pos_next))
-            h2 = self.runner.forward_batch(segs2)
+            with spans.span("runner.decode", batch=len(segs2)):
+                h2 = self.runner.forward_batch(segs2)
 
         # ---- decode emissions, in plan.decodes order (legacy parity)
-        for r in decs:
-            if r.done:
-                continue
-            logits = (h2[idx2[r.rid]] if r.rid in pending_rids
-                      else h1[seg_of[r.rid]])
-            if r.params.greedy:
-                nxt = int(np.asarray(jnp.argmax(logits)))
-            else:
-                nxt = sample_token(logits, r.params, len(r.generated))
-            r.metrics.decode_steps += 1
-            reason = self._emit(r, nxt, events)
-            self.block_mgr.commit(
-                r.rid, r.prompt_total + len(r.generated) - 1)
-            self._extend(r, nxt)
-            if reason is not None:
-                self._finish(r, reason)
+        live = [r for r in decs if not r.done]
+        spans.count("decode_rows", len(live))
+        if live:
+            with spans.span("engine.sample"):
+                nxts = []
+                for r in live:
+                    logits = (h2[idx2[r.rid]] if r.rid in pending_rids
+                              else h1[seg_of[r.rid]])
+                    nxts.append(int(np.asarray(jnp.argmax(logits)))
+                                if r.params.greedy else
+                                sample_token(logits, r.params,
+                                             len(r.generated)))
+            with spans.span("engine.emit"):
+                for r, nxt in zip(live, nxts):
+                    r.metrics.decode_steps += 1
+                    reason = self._emit(r, nxt, events)
+                    self.block_mgr.commit(
+                        r.rid, r.prompt_total + len(r.generated) - 1)
+                    self._extend(r, nxt)
+                    if reason is not None:
+                        self._finish(r, reason)
         return StepOutput(self.steps, tuple(events),
                           tuple(r.rid for r in self.finished[n_done:]),
                           len(self.active()), sched.num_queued(),
@@ -668,7 +705,11 @@ class Engine:
         the index rather than shipped — correctness needs only the live
         set (a preempted request therefore re-prefills from scratch after
         a consolidation; its stream is still bit-exact). The scheduling
-        policy and the waiting/preempted pools carry over."""
+        policy and the waiting/preempted pools carry over.
+
+        Spans: the successor's ``engine.init``, then ``engine.kv_gather``
+        (``moved_bytes``) and ``engine.rebuild_rows`` (the scheduler's
+        adoption and the block-table rebuild)."""
         self._check_live()
         eng = Engine(self.cfg, [full_params], self.max_batch, self.max_seq,
                      self.block_mgr.block_size, paged=self.paged,
@@ -678,32 +719,36 @@ class Engine:
                      kv_dtype=self.kv_dtype, fused=self.fused,
                      sanitize=False)   # the successor adopts OUR sanitizer
         stage_caches = [w.cache for w in self.runner.workers]
-        if self.paged:
-            self.block_mgr.drop_unreferenced_cache()
-            live_rids = [r.rid for r in self.active()]
-            live = self.block_mgr.blocks_of(live_rids)
-            cache, moved = gather_stage_caches_with_bytes(
-                stage_caches, live_blocks=live, target_stage=0,
-                tracer=self.block_mgr.tracer)
+        with spans.span("engine.kv_gather") as rec:
+            if self.paged:
+                self.block_mgr.drop_unreferenced_cache()
+                live_rids = [r.rid for r in self.active()]
+                live = self.block_mgr.blocks_of(live_rids)
+                rec.attrs["live_blocks"] = len(live)
+                cache, moved = gather_stage_caches_with_bytes(
+                    stage_caches, live_blocks=live, target_stage=0,
+                    tracer=self.block_mgr.tracer)
+                spans.count("moved_bytes", moved)
+                if self.sanitizer is not None:
+                    self.sanitizer.check_migration(
+                        moved, self.block_mgr.migration_bytes(
+                            live_rids,
+                            self.n_attn_layers(migrated_only=True)))
+                self.last_migration_bytes = moved
+                eng.last_migration_bytes = moved
+            else:
+                cache = gather_stage_caches(stage_caches)
+        with spans.span("engine.rebuild_rows"):
+            eng.runner.workers[0].cache = cache
+            eng.block_mgr = self.block_mgr
+            eng.scheduler.adopt(self.scheduler, self.block_mgr)
             if self.sanitizer is not None:
-                self.sanitizer.check_migration(
-                    moved, self.block_mgr.migration_bytes(
-                        live_rids,
-                        self.n_attn_layers(migrated_only=True)))
-            self.last_migration_bytes = moved
-            eng.last_migration_bytes = moved
-        else:
-            cache = gather_stage_caches(stage_caches)
-        eng.runner.workers[0].cache = cache
-        eng.block_mgr = self.block_mgr
-        eng.scheduler.adopt(self.scheduler, self.block_mgr)
-        if self.sanitizer is not None:
-            # rebind the tracer endpoints (runner / workers; the shared
-            # BlockManager already carries bm.tracer) BEFORE rebuild_rows
-            # so the successor's row writes are observed
-            eng.sanitizer = self.sanitizer
-            self.sanitizer.rebind(eng)
-        eng.runner.rebuild_rows(eng.active(), self.block_mgr.tables)
+                # rebind the tracer endpoints (runner / workers; the shared
+                # BlockManager already carries bm.tracer) BEFORE
+                # rebuild_rows so the successor's row writes are observed
+                eng.sanitizer = self.sanitizer
+                self.sanitizer.rebind(eng)
+            eng.runner.rebuild_rows(eng.active(), self.block_mgr.tables)
         eng._rid = self._rid
         eng.finished = self.finished
         eng.steps = self.steps            # keep step metrics continuous

@@ -27,6 +27,7 @@ from typing import Iterable, List, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.configs.base import ModelConfig
 from repro.serving.kvcache import KVInvariantError
 from repro.serving.worker import StageWorker
@@ -138,30 +139,32 @@ class ModelRunner:
         """One batched decode over ``reqs`` (each contributes its last
         generated token at its next cache position). ``skip_slots`` are
         live-but-not-decoding slots (half-prefilled residents) whose
-        table rows are masked to the null page for this forward."""
+        table rows are masked to the null page for this forward. One
+        ``runner.decode`` span: the inputs' build and the dispatch."""
         if self.tracer is not None:
             self.tracer.on_decode([(r.slot, r.pos_next) for r in reqs],
                                   list(skip_slots))
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        positions = np.zeros((self.max_batch, 1), np.int32)
-        for r in reqs:
-            tokens[r.slot, 0] = r.generated[-1]
-            positions[r.slot, 0] = r.pos_next
-        bt = None
-        if self.paged:
-            if skip_slots:
-                key = frozenset(skip_slots)
-                if self._masked_dev[0] != key:
-                    masked = self._bt.copy()
-                    masked[list(skip_slots)] = self._null_page
-                    self._masked_dev = (key, jnp.asarray(masked))
-                bt = self._masked_dev[1]
-            else:
-                bt = self._tables()
-        h = jnp.asarray(tokens)
-        pos = jnp.asarray(positions)
-        for w in self.workers:
-            h = w.decode(h, pos, block_tables=bt)
+        with spans.span("runner.decode", batch=len(reqs)):
+            tokens = np.zeros((self.max_batch, 1), np.int32)
+            positions = np.zeros((self.max_batch, 1), np.int32)
+            for r in reqs:
+                tokens[r.slot, 0] = r.generated[-1]
+                positions[r.slot, 0] = r.pos_next
+            bt = None
+            if self.paged:
+                if skip_slots:
+                    key = frozenset(skip_slots)
+                    if self._masked_dev[0] != key:
+                        masked = self._bt.copy()
+                        masked[list(skip_slots)] = self._null_page
+                        self._masked_dev = (key, jnp.asarray(masked))
+                    bt = self._masked_dev[1]
+                else:
+                    bt = self._tables()
+            h = jnp.asarray(tokens)
+            pos = jnp.asarray(positions)
+            for w in self.workers:
+                h = w.decode(h, pos, block_tables=bt)
         return h
 
     _TILE_Q = 8     # ragged span alignment (kernels/ragged_attention.py)

@@ -2,12 +2,13 @@
 
 ``StreamedStageLoader`` materializes a pipeline stage's parameters
 tensor-by-tensor in manifest order, straight from a ``ModelStore`` tier's
-byte ranges. Container / library / accelerator-context spans are stubbed
-from the ``TimingProfile`` (this process *is* already a warm runtime);
-fetch and load spans are **measured** — driven by the actual per-tensor
-byte counts through the contention-aware ``FetchSchedule`` (fetch) and a
-configured load bandwidth (PCIe leg). The result is a
-``WorkerTimeline``-compatible record honoring ``OverlapFlags``:
+byte ranges. Its timeline runs on the ``FetchSchedule``'s simulated
+clock: container / library / accelerator-context spans are stubbed from
+the ``TimingProfile`` (this process *is* already a warm runtime); fetch
+and load spans are computed from the actual per-tensor byte counts
+through the contention-aware ``FetchSchedule`` (fetch) and a configured
+load bandwidth (PCIe leg). The result is a ``WorkerTimeline``-compatible
+record honoring ``OverlapFlags``:
 
   * no ``prefetch``  — the fetch flow is admitted only after the full
     runtime init (container + lib + cuda), whichever order the flags
@@ -18,20 +19,24 @@ configured load bandwidth (PCIe leg). The result is a
     waits for all of it; with it, cc -> cuda and lib runs concurrent
     with loading (ready still waits for lib).
 
-Under matched bandwidths the measured spans converge to
+Under matched bandwidths these spans converge to
 ``core.coldstart.worker_timeline``'s analytic ones as tensor count grows
 (the stream pipeline's residual is one tensor's transfer) — asserted
 within 5% by tests and the fig8/fig9 ``--real-loader`` cross-checks.
+The wall-clock time of a stage's load is its ``loader.stage`` span
+(``repro.spans``).
 """
 
 from __future__ import annotations
 
 import math
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 
+from repro import spans
 from repro.core.coldstart import OverlapFlags, WorkerTimeline
 from repro.core.types import TimingProfile
 from repro.store.manifest import unflatten_paths
@@ -55,9 +60,10 @@ class TensorSpan:
 
 @dataclass
 class StageLoadRecord:
-    """Measured cold-start record for one stage worker —
-    ``timeline.spans`` uses the same stage names/conventions as the
-    analytic ``worker_timeline`` so the two are directly comparable."""
+    """Cold-start record for one stage worker on the fetch schedule's
+    simulated clock — ``timeline.spans`` uses the same stage
+    names/conventions as the analytic ``worker_timeline`` so the two are
+    directly comparable. Wall-clock time: the ``loader.stage`` span."""
     stage: int
     n_stages: int
     server_id: str
@@ -83,7 +89,8 @@ class StageLoadRecord:
 
 @dataclass
 class ColdStartReport:
-    """What a whole cold start measured: one record per stage worker."""
+    """A whole cold start on the simulated clock: one record per stage
+    worker. Wall-clock time: the ``frontend.cold_start`` span."""
     model: str
     s: int
     flags: OverlapFlags
@@ -110,8 +117,8 @@ class ColdStartReport:
 
 class StreamedStageLoader:
     """Loads stage parameter slices out of a ``ModelStore`` while
-    accounting a measured cold-start timeline on the fetch schedule's
-    simulated clock."""
+    accounting a cold-start timeline on the fetch schedule's simulated
+    clock (wall-clock time: the ``loader.stage`` spans)."""
 
     def __init__(self, store: ModelStore, schedule: FetchSchedule,
                  timings: Optional[TimingProfile] = None,
@@ -209,47 +216,62 @@ class _PendingStage:
         """Phase 2: resolve the fetch on the simulated clock and stream
         the tensors — each chunk range is *actually read* from the tier
         and built into the stage's param subtree; its fetch/load instants
-        come from the flow's measured byte-arrival profile."""
-        ld = self.loader
-        flags, spans = ld.flags, dict(self.spans)
-        flow = ld.schedule.resolve(self.flow)
-        plan = ld.store.stage_plan(self.n_stages, self.stage)
-        cuda_end = spans["cuda"][1]
-        lib_end = spans["lib"][1]
+        come from the flow's byte-arrival profile on that simulated clock.
 
-        fetch_end = flow.end
-        load_begin = max(cuda_end, flow.start)
-        cursor = load_begin if flags.stream \
-            else max(fetch_end, load_begin)
-        leaves = {}
-        tensors: List[TensorSpan] = []
-        cum = 0
-        for sc in plan:
-            arrive_begin = flow.time_at_bytes(cum)
-            cum += sc.length
-            arrive_end = flow.time_at_bytes(cum)
-            data = ld.store.read_range(sc.chunk, sc.offset, sc.length,
-                                       tier=ld.tier_name)
-            leaves[sc.chunk.path] = jnp.asarray(data.reshape(sc.shape))
-            if flags.stream:
-                # DMA chases the arrival stream: it can start on the
-                # tensor's first byte and finishes no earlier than its
-                # last byte lands (and no faster than the PCIe leg)
-                t0 = max(cursor, arrive_begin)
-                t1 = max(arrive_end, t0 + sc.length / ld.load_bw)
-            else:
-                t0 = cursor
-                t1 = t0 + sc.length / ld.load_bw
-            tensors.append(TensorSpan(sc.chunk.key, sc.length,
-                                      arrive_begin, arrive_end, t0, t1))
-            cursor = t1
-        load_end = max(cursor, fetch_end) if not tensors else cursor
-        spans["fetch"] = (flow.start, fetch_end)
-        spans["load"] = (load_begin, load_end)
-        ready = max(load_end, lib_end)
-        assert all(s0 <= s1 + 1e-12 for s0, s1 in spans.values())
-        timeline = WorkerTimeline(ready=ready, spans=spans)
-        record = StageLoadRecord(self.stage, self.n_stages, self.server_id,
-                                 ld.tier_name, int(flow.size), timeline,
-                                 tensors)
-        return unflatten_paths(leaves), record
+        Wall-clock time is the ``loader.stage`` span (``repro.spans``):
+        ``bytes``, ``tensors``, ``read_ns`` (the tier reads) and
+        ``put_ns`` (dispatching the host->device copies, which it does
+        not wait for)."""
+        with spans.span("loader.stage", stage=self.stage,
+                        bytes=int(self.flow.size)):
+            ld = self.loader
+            flags, sim = ld.flags, dict(self.spans)
+            flow = ld.schedule.resolve(self.flow)
+            plan = ld.store.stage_plan(self.n_stages, self.stage)
+            cuda_end = sim["cuda"][1]
+            lib_end = sim["lib"][1]
+
+            fetch_end = flow.end
+            load_begin = max(cuda_end, flow.start)
+            cursor = load_begin if flags.stream \
+                else max(fetch_end, load_begin)
+            leaves = {}
+            tensors: List[TensorSpan] = []
+            cum = 0
+            read_ns = put_ns = 0
+            for sc in plan:
+                arrive_begin = flow.time_at_bytes(cum)
+                cum += sc.length
+                arrive_end = flow.time_at_bytes(cum)
+                w0 = time.perf_counter_ns()
+                data = ld.store.read_range(sc.chunk, sc.offset, sc.length,
+                                           tier=ld.tier_name)
+                w1 = time.perf_counter_ns()
+                leaves[sc.chunk.path] = jnp.asarray(data.reshape(sc.shape))
+                read_ns += w1 - w0
+                put_ns += time.perf_counter_ns() - w1
+                if flags.stream:
+                    # DMA chases the arrival stream: it can start on the
+                    # tensor's first byte and finishes no earlier than its
+                    # last byte lands (and no faster than the PCIe leg)
+                    t0 = max(cursor, arrive_begin)
+                    t1 = max(arrive_end, t0 + sc.length / ld.load_bw)
+                else:
+                    t0 = cursor
+                    t1 = t0 + sc.length / ld.load_bw
+                tensors.append(TensorSpan(sc.chunk.key, sc.length,
+                                          arrive_begin, arrive_end, t0, t1))
+                cursor = t1
+            spans.count("tensors", len(plan))
+            spans.count("read_ns", read_ns)
+            spans.count("put_ns", put_ns)
+            load_end = max(cursor, fetch_end) if not tensors else cursor
+            sim["fetch"] = (flow.start, fetch_end)
+            sim["load"] = (load_begin, load_end)
+            ready = max(load_end, lib_end)
+            assert all(s0 <= s1 + 1e-12 for s0, s1 in sim.values())
+            timeline = WorkerTimeline(ready=ready, spans=sim)
+            record = StageLoadRecord(self.stage, self.n_stages, self.server_id,
+                                     ld.tier_name, int(flow.size), timeline,
+                                     tensors)
+            return unflatten_paths(leaves), record
