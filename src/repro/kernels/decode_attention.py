@@ -7,11 +7,14 @@ Two variants share the online-softmax inner loop:
   scratch. Per-sequence valid length ``kv_len`` masks the tail.
 * ``paged_decode_attention`` — vLLM-style paged caches: a shared page pool
   ``(N, bs, Hkv, hd)`` addressed through a per-sequence block table
-  ``(B, nb)``. The table is a scalar-prefetch operand so the K/V BlockSpec
-  index maps gather the right page for each (sequence, step) before the
-  kernel body runs. Each grid step loads one page with *every* KV head —
-  a ``(1, bs, Hkv, hd)`` block, whose last two dims equal the pool's, as
-  the TPU tiling requires — and the body walks the heads.
+  ``(B, nb)``, both scalar-prefetch operands. The pools stay in HBM; one
+  grid step per sequence walks only its live blocks, ``cdiv(kv_len,
+  block)`` of them, each ``block >= 128`` tokens of whole pages. Each
+  block's pages arrive by one DMA per page (pages are not contiguous)
+  into a double-buffered VMEM block, with *every* KV head — ``(bs, Hkv,
+  hd)`` per page, whose last two dims equal the pool's, as the TPU tiling
+  requires — while the previous block is computed; the body walks the
+  heads, one softmax step per head and block.
 
 ``kv_len`` rides as a scalar-prefetch (SMEM) operand in both: a ``(1, 1)``
 VMEM block over a ``(B, 1)`` array is not a legal TPU block shape.
@@ -29,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_LANES = 128
 
 
 def _softmax_step(q, k, v, kpos, valid_len, m_scr, l_scr, acc_scr, h: int,
@@ -139,29 +143,83 @@ def decode_attention(q, k_cache, v_cache, kv_len, *,
     return out.reshape(b, 1, hq, hd)
 
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float, page_size: int):
+def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, state, m_scr, l_scr, acc_scr, *,
+                  scale: float, page_size: int, pages_per_block: int):
+    """One grid step per row, walking only the row's live blocks of
+    ``pages_per_block`` pages. Each block's pages are copied, one DMA per
+    page, into one slot of the double-buffered ``k_buf``/``v_buf`` while
+    the block before it is computed; a row's last block starts the next
+    row's first. ``state`` (SMEM) carries across rows the slot of the next
+    block and whether that row's first block is in flight."""
     bi = pl.program_id(0)
-    ib = pl.program_id(1)
-    nb = pl.num_programs(1)
+    n_rows = pl.num_programs(0)
+    nb = bt_ref.shape[1]
+    ppb = pages_per_block
+    bk = ppb * page_size
+    n_heads = q_ref.shape[1]
 
-    @pl.when(ib == 0)
-    def _init():
-        _softmax_init(m_scr, l_scr, acc_scr)
+    def n_blocks(row):
+        return (len_ref[row] + bk - 1) // bk
 
-    kpos = ib * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)                 # (1, bs)
+    def dma(row, blk, slot, wait=False):
+        """Start (or wait for) the copies of one block's pages."""
+        def page(p, carry):
+            # a table not a multiple of the block wide: the last block's
+            # tail pages, past any kv_len, re-read the table's last entry
+            idx = bt_ref[row, jnp.minimum(blk * ppb + p, nb - 1)]
+            for kv, (src, buf) in enumerate(((k_hbm, k_buf),
+                                             (v_hbm, v_buf))):
+                c = pltpu.make_async_copy(src.at[idx], buf.at[slot, p],
+                                          sems.at[kv, slot])
+                if wait:
+                    c.wait()
+                else:
+                    c.start()
+            return carry
+
+        jax.lax.fori_loop(0, ppb, page, 0)
+
+    @pl.when(bi == 0)
+    def _first_row():
+        state[0] = 0
+        state[1] = 0
+
+    n = n_blocks(bi)
+    slot0 = state[0]
+
+    @pl.when((n > 0) & (state[1] == 0))
+    def _start_first_block():
+        dma(bi, 0, slot0)
+
+    _softmax_init(m_scr, l_scr, acc_scr)
+    nxt = jnp.minimum(bi + 1, n_rows - 1)
+    next_row_live = (bi + 1 < n_rows) & (n_blocks(nxt) > 0)
     valid = len_ref[bi]
-    for h in range(q_ref.shape[1]):                   # every KV head
-        q = q_ref[0, h].astype(jnp.float32)           # (G, hd)
-        k = k_ref[0, :, h, :].astype(jnp.float32)     # (bs, hd)
-        v = v_ref[0, :, h, :].astype(jnp.float32)
-        _softmax_step(q, k, v, kpos, valid, m_scr, l_scr, acc_scr, h, scale)
 
-    @pl.when(ib == nb - 1)
-    def _finish():
-        for h in range(q_ref.shape[1]):
-            o_ref[0, h] = _softmax_out(l_scr, acc_scr, h).astype(o_ref.dtype)
+    def block(j, slot):
+        # prefetch the row's next block, or after its last the next row's
+        # first
+        more = j + 1 < n
+
+        @pl.when(more | next_row_live)
+        def _prefetch():
+            dma(jnp.where(more, bi, nxt), jnp.where(more, j + 1, 0), 1 - slot)
+
+        dma(bi, j, slot, wait=True)
+        kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        for h in range(n_heads):                      # every KV head
+            q = q_ref[0, h].astype(jnp.float32)       # (G, hd)
+            k = k_buf[slot, :, :, h, :].astype(jnp.float32)
+            v = v_buf[slot, :, :, h, :].astype(jnp.float32)
+            _softmax_step(q, k.reshape(bk, -1), v.reshape(bk, -1), kpos,
+                          valid, m_scr, l_scr, acc_scr, h, scale)
+        return 1 - slot
+
+    state[0] = jax.lax.fori_loop(0, n, block, slot0)
+    state[1] = ((n > 0) & next_row_live).astype(jnp.int32)
+    for h in range(n_heads):
+        o_ref[0, h] = _softmax_out(l_scr, acc_scr, h).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_len, *,
@@ -172,7 +230,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_len, *,
 
     Rows of ``block_tables`` past a sequence's live length may hold any
     valid page id (conventionally 0): the ``kv_len`` mask zeroes their
-    contribution.
+    contribution. A row reads ``cdiv(kv_len, block)`` blocks of
+    ``block`` >= 128 tokens (whole pages, at most the table) and no more;
+    a ``kv_len == 0`` row reads none and returns zeros.
     """
     b, one, hq, hd = q.shape
     assert one == 1
@@ -180,26 +240,30 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_len, *,
     nb = block_tables.shape[1]
     group = hq // hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
+    # enough pages for a full lane width of scores per block
+    pages_per_block = min(-(-_LANES // page_size), nb)
 
     qg = q[:, 0].reshape(b, hkv, group, hd)           # (B,Hkv,G,hd)
     lens = kv_len.astype(jnp.int32).reshape(b)
     tables = block_tables.astype(jnp.int32)
 
     kernel = functools.partial(_paged_kernel, scale=scale,
-                               page_size=page_size)
-    page = lambda bi, ib, bt, ln: (bt[bi, ib], 0, 0, 0)
+                               page_size=page_size,
+                               pages_per_block=pages_per_block)
+    row = lambda bi, bt, ln: (bi, 0, 0, 0)
+    buf = pltpu.VMEM((2, pages_per_block, page_size, hkv, hd), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, nb),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, hkv, group, hd),
-                         lambda bi, ib, bt, ln: (bi, 0, 0, 0)),
-            pl.BlockSpec((1, page_size, hkv, hd), page),
-            pl.BlockSpec((1, page_size, hkv, hd), page),
+            pl.BlockSpec((1, hkv, group, hd), row),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, hkv, group, hd),
-                               lambda bi, ib, bt, ln: (bi, 0, 0, 0)),
-        scratch_shapes=_softmax_scratch(hkv, group, hd),
+        out_specs=pl.BlockSpec((1, hkv, group, hd), row),
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((2,), jnp.int32),
+                        *_softmax_scratch(hkv, group, hd)],
     )
 
     out = pl.pallas_call(
@@ -207,7 +271,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_len, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         metadata={"kernel": "paged_decode_attention"},
     )(tables, lens, qg, k_pages, v_pages)

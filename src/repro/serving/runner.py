@@ -47,6 +47,7 @@ class ModelRunner:
         # their (unused) decode writes never land in a live page; the
         # ragged path also routes pad-token writes to it
         self._null_page = n_blocks
+        self._block_size = block_size
         self._table_width = max_seq // block_size + 1
         n = len(stage_params)
         self.workers = [StageWorker(cfg, p, n, i, max_batch, max_seq,
@@ -140,7 +141,10 @@ class ModelRunner:
         generated token at its next cache position). ``skip_slots`` are
         live-but-not-decoding slots (half-prefilled residents) whose
         table rows are masked to the null page for this forward. One
-        ``runner.decode`` span: the inputs' build and the dispatch."""
+        ``runner.decode`` span: the inputs' build and the dispatch. On
+        the paged layout it counts ``kv_pages_live``, the pages the
+        attention kernel reads (Σ ``cdiv(kv_len, block_size)`` over the
+        rows), and ``kv_pages_table``, rows × table width."""
         if self.tracer is not None:
             self.tracer.on_decode([(r.slot, r.pos_next) for r in reqs],
                                   list(skip_slots))
@@ -152,6 +156,10 @@ class ModelRunner:
                 positions[r.slot, 0] = r.pos_next
             bt = None
             if self.paged:
+                bs = self._block_size
+                spans.count("kv_pages_live",
+                            sum(r.pos_next // bs + 1 for r in reqs))
+                spans.count("kv_pages_table", len(reqs) * self._table_width)
                 if skip_slots:
                     key = frozenset(skip_slots)
                     if self._masked_dev[0] != key:

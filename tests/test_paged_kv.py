@@ -37,36 +37,57 @@ def _tol(dtype):
     return 2e-5 if dtype == jnp.float32 else 3e-2
 
 
-def _paged_case(rng, b, hq, hkv, hd, page_size, nb, dtype):
+def _paged_case(rng, b, hq, hkv, hd, page_size, nb, dtype, lens=None):
+    """Random q and pools and a shuffled table that leaves page 0 (the
+    null page) to no row. With ``lens`` given, each row's table past its
+    live pages holds garbage but valid ids, the null page among them."""
     n_pages = b * nb + 1
     q = jnp.asarray(rng.standard_normal((b, 1, hq, hd)), dtype)
     kp = jnp.asarray(rng.standard_normal((n_pages, page_size, hkv, hd)),
                      dtype)
     vp = jnp.asarray(rng.standard_normal((n_pages, page_size, hkv, hd)),
                      dtype)
-    # non-trivial page assignment: shuffled, page 0 unused by any table
     perm = rng.permutation(n_pages - 1) + 1
-    bt = jnp.asarray(perm[: b * nb].reshape(b, nb), jnp.int32)
-    lens = jnp.asarray(rng.integers(1, nb * page_size + 1, b), jnp.int32)
-    return q, kp, vp, bt, lens
+    bt = perm[: b * nb].reshape(b, nb)
+    if lens is None:
+        lens = rng.integers(1, nb * page_size + 1, b)
+    else:
+        for r, n in enumerate(lens):
+            live = -(-n // page_size)
+            tail = rng.integers(0, n_pages, nb - live)
+            tail[::2] = 0
+            bt[r, live:] = tail
+    return (q, kp, vp, jnp.asarray(bt, jnp.int32),
+            jnp.asarray(lens, jnp.int32))
+
+
+# At page 16 the kernel reads blocks of 8 pages (128 tokens); a table of
+# 19 pages is no multiple of that. The edge lengths: an empty row, one
+# token, a page, a page and one, a block, a block and one, two blocks and
+# one, the whole table.
+EDGES = [0, 1, 16, 17, 128, 129, 257, 19 * 16]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,hq,hkv,hd,page_size,nb", [
-    (2, 8, 2, 64, 16, 5),
-    (3, 4, 4, 32, 64, 3),
-    (2, 6, 1, 64, 16, 4),
-    (1, 16, 8, 128, 64, 2),
+@pytest.mark.parametrize("b,hq,hkv,hd,page_size,nb,lens", [
+    pytest.param(2, 8, 2, 64, 16, 5, None, id="2-8-2-64-16-5"),
+    pytest.param(3, 4, 4, 32, 64, 3, None, id="3-4-4-32-64-3"),
+    pytest.param(2, 6, 1, 64, 16, 4, None, id="2-6-1-64-16-4"),
+    pytest.param(1, 16, 8, 128, 64, 2, None, id="1-16-8-128-64-2"),
+    pytest.param(8, 8, 2, 128, 16, 19, EDGES, id="edges-group4"),
+    pytest.param(8, 12, 2, 128, 16, 19, EDGES[::-1], id="edges-group6"),
 ])
 def test_paged_decode_kernel_matches_oracle(b, hq, hkv, hd, page_size, nb,
-                                            dtype):
+                                            lens, dtype):
     rng = np.random.default_rng(11)
     q, kp, vp, bt, lens = _paged_case(rng, b, hq, hkv, hd, page_size, nb,
-                                      dtype)
-    # oracle: gather the table into a contiguous cache, masked attention
+                                      dtype, lens)
+    # oracle: gather the table into a contiguous cache, masked attention;
+    # a row with no context reads nothing and returns zeros
     kc = kp[bt].reshape(b, nb * page_size, hkv, hd)
     vc = vp[bt].reshape(b, nb * page_size, hkv, hd)
     want = ref.decode_attention_reference(q, kc, vc, lens)
+    want = jnp.where((lens > 0)[:, None, None, None], want, 0)
 
     got_kernel = paged_decode_attention(q, kp, vp, bt, lens, interpret=True)
     got_ref = ref.paged_decode_attention_reference(q, kp, vp, bt, lens)

@@ -232,3 +232,33 @@ def test_fused_step_records_the_same_spans(deployed):
     for fused in (False, True):
         assert names[fused]["engine.sample"] == \
             names[fused]["engine.emit"] == 4
+
+
+def test_paged_decode_counts_live_and_table_pages(deployed, monkeypatch):
+    """``runner.decode`` counts the pages the paged kernel reads, Σ
+    ``cdiv(kv_len, block_size)`` over the decoded rows, beside the rows ×
+    table width the whole-table walk read."""
+    cfg, params, _ = deployed
+    eng = Engine(cfg, [params], max_batch=2, max_seq=64, block_size=16,
+                 paged=True, fused=False)
+    lens_seen = []
+    decode = eng.runner.decode
+
+    def recording(reqs, skip_slots=()):
+        lens_seen.append([r.pos_next + 1 for r in reqs])
+        return decode(reqs, skip_slots=skip_slots)
+
+    monkeypatch.setattr(eng.runner, "decode", recording)
+    # the first prompt's rows cross a page boundary while decoding
+    handles = [eng.submit(list(range(1, 15)), SamplingParams(max_new=6)),
+               eng.submit([9, 2], SamplingParams(max_new=6))]
+    mark = last_index()
+    _drive(eng, handles, 6)
+    recs = [r for r in since(mark) if r.name == "runner.decode"]
+    assert len(recs) == len(lens_seen) > 0
+    assert any(n > 16 for lens in lens_seen for n in lens)
+    width = 64 // 16 + 1
+    for rec, lens in zip(recs, lens_seen):
+        assert rec.counters["kv_pages_live"] == sum(-(-n // 16)
+                                                    for n in lens)
+        assert rec.counters["kv_pages_table"] == len(lens) * width

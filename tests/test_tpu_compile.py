@@ -4,7 +4,8 @@ No chip is needed: the TPU compiler builds for a described ``v5e:2x2``
 topology and refuses what the chip would refuse (block shapes that break
 the (8, 128) tiling, too much VMEM), which interpret mode never checks.
 Shapes are granite-3-8b's widths (32 q / 8 kv heads, head_dim 128,
-page 16, bf16) at the serving engine's pool size for 8 x 2048 tokens.
+page 16, bf16) at the serving engine's pool size for 8 x 2048 tokens;
+the paged decode kernel also at internlm2-20b's (48 q / 8 kv heads).
 
 The topology is described inside a fixture: only one process at a time
 may load the TPU library, so no module does it while being imported.
@@ -24,6 +25,7 @@ from repro.kernels import flash_attention as fa
 from repro.kernels import ragged_attention as ra
 
 B, HQ, HKV, HD, PAGE = 8, 32, 8, 128, 16
+HQ_GROUP6 = 48                           # internlm2-20b: GQA group 6
 MAX_SEQ = 2048
 NB = MAX_SEQ // PAGE + 1                 # block-table width (runner.py)
 N_PAGES = B * NB + 1                     # pool blocks + the null page
@@ -75,7 +77,8 @@ def _structs(one_chip, *shapes_dtypes):
 @pytest.mark.parametrize("kernel", ["flash_attention", "decode_attention",
                                     "paged_decode_attention",
                                     "ragged_paged_attention",
-                                    "ragged_paged_attention_int8"])
+                                    "ragged_paged_attention_int8",
+                                    "paged_decode_attention_group6"])
 def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache):
     bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
     if kernel == "flash_attention":
@@ -87,11 +90,15 @@ def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache):
                         ((B, MAX_SEQ, HKV, HD), bf),
                         ((B, MAX_SEQ, HKV, HD), bf), ((B,), i32))
         fn = da.decode_attention
-    elif kernel == "paged_decode_attention":
-        q, tables, lens = _structs(one_chip, ((B, 1, HQ, HD), bf),
+    elif kernel.startswith("paged_decode_attention"):
+        hq = HQ_GROUP6 if kernel.endswith("group6") else HQ
+        q, tables, lens = _structs(one_chip, ((B, 1, hq, HD), bf),
                                    ((B, NB), i32), ((B,), i32))
         args = [q, _pool(one_chip), _pool(one_chip), tables, lens]
         fn = da.paged_decode_attention
+        # one kernel launch per attention layer and step
+        lowered = jax.jit(fn).lower(*args).as_text()
+        assert lowered.count("tpu_custom_call") == 1
     else:
         q, tables, row, pos = _structs(one_chip, ((T, HQ, HD), bf),
                                        ((B, NB), i32), ((T,), i32),
