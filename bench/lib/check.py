@@ -18,6 +18,10 @@ from typing import Dict, List, Sequence
 from bench.lib import traffic
 
 BENCH = Path(__file__).resolve().parents[1]
+# A configuration's ``reference`` names its architecture module here: the
+# plain reference forward, and what the configuration's keys mean (the
+# program's model config, the served tree's layout, a step's work).
+REFERENCES = BENCH / "references"
 
 
 def load_module(path: Path, name: str):
@@ -28,7 +32,8 @@ def load_module(path: Path, name: str):
 
 
 def reference_module(cfg: dict):
-    return load_module(BENCH / "references" / f"{cfg['reference']}.py",
+    """The architecture module that ``cfg["reference"]`` names."""
+    return load_module(REFERENCES / f"{cfg['reference']}.py",
                        f"bench_reference_{cfg['reference']}")
 
 

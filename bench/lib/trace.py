@@ -11,6 +11,7 @@ the program and of JAX. All times are nanoseconds on the trace's clock.
 from __future__ import annotations
 
 import glob
+import json
 import os
 import re
 from collections import defaultdict
@@ -130,16 +131,60 @@ def leaves(events: Sequence[Event]) -> List[Event]:
             if nxt is None or nxt.start >= e.end]
 
 
-def kernel_ns(events: Sequence[Event], lo: float, hi: float,
-              needle: str) -> Tuple[float, int]:
-    """Device time and count of the operations whose full text contains
-    ``needle``."""
+_SUFFIX = re.compile(r"\.\d+$")
+_TARGET = re.compile(r'custom_call_target="([^"]*)"')
+_META = "kernel_metadata="
+
+
+def op_name(text: str) -> str:
+    """The own name of a device operation's HLO instruction, without
+    ``%`` and the ``.N`` suffix: ``%tpu_custom_call.9 = bf16[8,4]{1,0}
+    custom-call(%a)`` -> ``tpu_custom_call``."""
+    head = text.partition(" = ")[0].split()
+    return _SUFFIX.sub("", head[-1].lstrip("%")) if head else ""
+
+
+def kernel_metadata(text: str) -> dict:
+    """The ``kernel_metadata`` a Pallas call carries among its own
+    attributes ({} where it carries none). Operands name other
+    instructions and carry no attributes, so the first such attribute in
+    an operation's text is its own."""
+    i = text.find(_META)
+    if i < 0:
+        return {}
+    try:
+        meta, _ = json.JSONDecoder().raw_decode(text, i + len(_META))
+    except ValueError:
+        return {}
+    return meta if isinstance(meta, dict) else {}
+
+
+def _ops_ns(events: Sequence[Event], lo: float, hi: float,
+            match) -> Tuple[float, int]:
     total, n = 0.0, 0
     for e, s, t in _clip(events, lo, hi):
-        if needle in e.text:
+        if match(e.text):
             total += t - s
             n += 1
     return total, n
+
+
+def kernel_ns(events: Sequence[Event], lo: float, hi: float,
+              kernel: str) -> Tuple[float, int]:
+    """Device time and count of the calls of the kernel ``kernel``: the
+    operations of that own name, or whose ``kernel_metadata`` names it;
+    an operation that reads a call's output names it only as an operand
+    and is not one."""
+    return _ops_ns(events, lo, hi, lambda text: (
+        op_name(text) == kernel
+        or kernel_metadata(text).get("kernel") == kernel))
+
+
+def custom_call_ns(events: Sequence[Event], lo: float, hi: float,
+                   target: str) -> Tuple[float, int]:
+    """Device time and count of the custom calls to ``target``."""
+    return _ops_ns(events, lo, hi,
+                   lambda text: target in _TARGET.findall(text))
 
 
 def idle_gaps(events: Sequence[Event], host: Sequence[Event], lo: float,

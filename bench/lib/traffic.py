@@ -3,10 +3,12 @@ under ``bench/traffic/``) into requests.
 
 Lengths are a fixed set, the same for every seed: ``n`` stratified
 quantiles, at (i + 0.5) / n, of a lognormal with the given mean and
-sigma, rounded and clipped. The seed decides only the order, the pairing
-of prompt and output lengths, and the token ids. So two seeds do the same
-work in another order, and a warm-up with the same lengths builds every
-shape the window uses.
+sigma, rounded and clipped. Prompt and output lengths are paired once,
+the same way for every seed and cycle. The seed decides only the order
+of the requests and their token ids. So two seeds do the same work in
+another order (the paged decode step's time follows each row's context,
+so a pairing drawn by the seed would change the work), and a warm-up
+with the same lengths builds every shape the window uses.
 """
 
 from __future__ import annotations
@@ -40,17 +42,23 @@ def rng(seed: int, *stream: int) -> np.random.Generator:
     return np.random.default_rng([seed % 2 ** 64, *stream])
 
 
-def burst(mix: dict, vocab: int, seed: int, cycle: int) -> List[Request]:
-    """The requests of one burst: the mix's fixed lengths, paired and
-    ordered by the seed, with token ids uniform over the vocabulary."""
+def pairs(mix: dict) -> List[tuple]:
+    """The burst's (prompt, output) lengths: the i-th prompt quantile
+    with the output quantiles in one fixed permutation, drawn from a
+    stream that no seed reaches."""
     n = mix["burst"]
-    prompts = lengths(mix["prompt_len"], n)
     outputs = lengths(mix["output_len"], n)
+    return list(zip(lengths(mix["prompt_len"], n),
+                    [outputs[i] for i in rng(0, 2 ** 32 + 2).permutation(n)]))
+
+
+def burst(mix: dict, vocab: int, seed: int, cycle: int) -> List[Request]:
+    """The requests of one burst: the mix's fixed pairs, ordered by the
+    seed, with token ids uniform over the vocabulary."""
+    fixed = pairs(mix)
     r = rng(seed, cycle)
-    prompts = [prompts[i] for i in r.permutation(n)]
-    outputs = [outputs[i] for i in r.permutation(n)]
-    return [Request(r.integers(0, vocab, p).tolist(), o)
-            for p, o in zip(prompts, outputs)]
+    return [Request(r.integers(0, vocab, fixed[i][0]).tolist(), fixed[i][1])
+            for i in r.permutation(len(fixed))]
 
 
 def warmup_burst(mix: dict, vocab: int) -> List[Request]:

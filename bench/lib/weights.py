@@ -1,21 +1,24 @@
-"""Seeded random weights for a dense GQA decoder, made on the device.
+"""Seeded random weights, made on the device.
 
-One layout serves both sides of the comparison: ``make_params`` builds
-the whole tree in one jitted call, in the served dtype, to hand to the
-system under test; ``layer_leaf`` rebuilds any single leaf of any layer
-for the reference, bit for bit the same, so that the reference never
-holds more than one layer and never reads what the system made.
+A configuration's architecture module (``bench/references/<reference>.py``)
+lists the leaves of the tree the program serves, in a fixed order, with
+``layout(cfg)``. Over that list, ``make_params`` builds the whole tree in
+one jitted call, in the served dtype, to hand to the system under test;
+``layer_leaf`` rebuilds any single leaf of any layer for the reference,
+bit for bit the same, so that the reference never holds more than one
+layer and never reads what the system made.
 
 Each leaf is uniform on [-sqrt(3) std, sqrt(3) std) (so its standard
 deviation is ``std``), made from threefry bits with integer and exact
 float arithmetic only: a vmapped and a single-layer call give the same
-bits.
+bits. A leaf's key is the seed's key folded with the leaf's index in the
+layout and the index along its stack, so the order fixes every key.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict, List, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +28,8 @@ ROOT_TAG = 0x62656e63          # folded into the seed's key: "benc"
 
 class Leaf(NamedTuple):
     path: Tuple[str, ...]       # key path in the served parameter tree
-    shape: Tuple[int, ...]      # one layer's shape (without the layer axis)
-    stacked: bool               # one per layer, stacked on a leading axis
+    shape: Tuple[int, ...]      # one layer's shape (without the stack axis)
+    stack: int                  # length of the leading stack axis; 0: none
     std: float                  # 0 means a vector of ones (a norm's scale)
 
 
@@ -34,30 +37,6 @@ def padded_vocab(vocab: int) -> int:
     """Rows of the embedding and columns of the head as the served tree
     holds them: the vocabulary rounded up to a multiple of 256."""
     return -(-vocab // 256) * 256
-
-
-def layout(cfg: dict) -> List[Leaf]:
-    """Every leaf of the served tree of a dense GQA decoder with SwiGLU
-    and RMSNorm, in a fixed order (the order fixes each leaf's key)."""
-    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
-    hd = cfg["head_dim"]
-    hq, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    vp = padded_vocab(cfg["vocab_size"])
-    blk = ("blocks", "slot00")
-    return [
-        Leaf(("embed", "tok"), (vp, d), False, 1.0),
-        Leaf(blk + ("mixer", "w_q"), (d, hq * hd), True, d ** -0.5),
-        Leaf(blk + ("mixer", "w_k"), (d, hkv * hd), True, d ** -0.5),
-        Leaf(blk + ("mixer", "w_v"), (d, hkv * hd), True, d ** -0.5),
-        Leaf(blk + ("mixer", "w_o"), (hq * hd, d), True, (hq * hd) ** -0.5),
-        Leaf(blk + ("mixer", "norm"), (d,), True, 0.0),
-        Leaf(blk + ("mlp", "w_gate"), (d, ff), True, d ** -0.5),
-        Leaf(blk + ("mlp", "w_up"), (d, ff), True, d ** -0.5),
-        Leaf(blk + ("mlp", "w_down"), (ff, d), True, ff ** -0.5),
-        Leaf(blk + ("mlp", "norm"), (d,), True, 0.0),
-        Leaf(("final_norm",), (d,), False, 0.0),
-        Leaf(("lm_head",), (d, vp), False, d ** -0.5),
-    ]
 
 
 def seed_key(seed: int):
@@ -83,27 +62,25 @@ def _leaf_key(key, index: int, layer: int):
     return jax.random.fold_in(jax.random.fold_in(key, index), layer)
 
 
-def layer_leaf(key, cfg: dict, index: int, layer: int, dtype):
-    """Leaf ``index`` of ``layout(cfg)`` for one layer (0 for leaves that
-    are not stacked), in ``dtype``."""
-    leaf = layout(cfg)[index]
+def layer_leaf(key, leaves: Sequence[Leaf], index: int, layer: int, dtype):
+    """Leaf ``index`` of the layout ``leaves`` at position ``layer`` of
+    its stack (0 for a leaf that is not stacked), in ``dtype``."""
+    leaf = leaves[index]
     return _uniform(_leaf_key(key, index, layer), leaf.shape, leaf.std,
                     dtype)
 
 
-def make_params(cfg: dict, seed: int, dtype) -> Dict:
-    """The whole served tree, made on the default device in one jitted
-    call."""
-    n_layers = cfg["num_hidden_layers"]
-    leaves = layout(cfg)
+def make_params(leaves: Sequence[Leaf], seed: int, dtype) -> Dict:
+    """The whole served tree of the layout ``leaves``, made on the default
+    device in one jitted call."""
 
     def build(key):
         tree: Dict = {}
         for i, leaf in enumerate(leaves):
-            if leaf.stacked:
+            if leaf.stack:
                 val = jax.vmap(lambda l, i=i, leaf=leaf: _uniform(
                     _leaf_key(key, i, l), leaf.shape, leaf.std, dtype))(
-                    jnp.arange(n_layers))
+                    jnp.arange(leaf.stack))
             else:
                 val = _uniform(_leaf_key(key, i, 0), leaf.shape, leaf.std,
                                dtype)

@@ -3,22 +3,25 @@ least time the chip needs for the paged decode attention calls of the
 traced steps, over their device time, in percent.
 
 The least time is the larger of the calls' operations over the peak
-FLOP/s and their bytes over the peak HBM bandwidth, with operations and
-bytes from each request's actual context length (``bench.lib.flops``),
-not from the block table's width. The kernel is found in the trace by
-its function name, ``KERNEL``. The program gives its ``pallas_call``s no
-name yet, and then its trace holds none: the reader then takes the
-decode step's Pallas kernels (``PALLAS``), of which the paged decode
-attention is the only one. Either way it reads only where it finds one
-call per attention layer per traced step, and leaves the metric out
+FLOP/s and their bytes over the peak HBM bandwidth, with the calls,
+operations and bytes of each step counted by the configuration's
+architecture module (``paged_decode_work``) from each request's actual
+context length, not from the block table's width. The kernel is found in
+the trace as the operation named ``KERNEL``, or the one whose
+``kernel_metadata`` names it. A program that names neither (before it
+gave its kernels metadata) is read by the decode step's Pallas kernels,
+the custom calls to ``PALLAS``, of which the paged decode attention was
+the only one. Either way it reads only where it finds as many calls as
+the module counts for the traced steps, and leaves the metric out
 otherwise.
 """
 
 from bench.lib import trace as tr
-from bench.lib.flops import paged_decode_call, roofline_seconds
+from bench.lib.check import reference_module
+from bench.lib.flops import roofline_seconds
 
 KERNEL = "paged_decode_attention"
-PALLAS = 'custom_call_target="tpu_custom_call"'
+PALLAS = "tpu_custom_call"
 
 
 def read(run):
@@ -27,14 +30,15 @@ def read(run):
         return None
     ns, n_calls = tr.kernel_ns(t.events, t.lo, t.hi, KERNEL)
     if not n_calls:
-        ns, n_calls = tr.kernel_ns(t.events, t.lo, t.hi, PALLAS)
-    layers = run.cfg["num_hidden_layers"]
-    if ns <= 0 or n_calls != layers * len(run.traced_steps):
-        return None
-    flops = nbytes = 0
+        ns, n_calls = tr.custom_call_ns(t.events, t.lo, t.hi, PALLAS)
+    arch = reference_module(run.cfg)
+    calls = flops = nbytes = 0
     for lens in run.traced_steps:
-        f, b = paged_decode_call(run.cfg, lens)
-        flops += layers * f
-        nbytes += layers * b
+        c, f, b = arch.paged_decode_work(run.cfg, lens)
+        calls += c
+        flops += f
+        nbytes += b
+    if ns <= 0 or n_calls != calls:
+        return None
     least, _ = roofline_seconds(flops, nbytes, run.peak)
     return 100.0 * least / (ns * 1e-9)

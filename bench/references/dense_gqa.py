@@ -1,7 +1,19 @@
-"""Plain reference forward of a dense GQA decoder, in float32.
+"""The dense GQA decoder: what its configuration keys mean, and its plain
+reference forward in float32.
 
-The published block of granite-3-8b and internlm2-20b as this benchmark
-runs them: token embedding; per layer RMSNorm, grouped-query attention
+As every architecture module under ``bench/references/``, it gives the
+harness, which reads no key of an architecture's own:
+
+- ``model_config(cfg)``: the program's ``ModelConfig``;
+- ``layout(cfg)``: every leaf of the served tree, in a fixed order
+  (``bench.lib.weights``);
+- ``decode_step_flops(cfg, kv_lens)``: model FLOPs of one decode step;
+- ``paged_decode_work(cfg, kv_lens)``: (kernel calls, operations, bytes)
+  of one decode step's paged attention, over every attention layer;
+- ``Reference(cfg, seed)``: the plain forward that decides ``correct``.
+
+The reference forward is the published block of granite-3-8b and
+internlm2-20b as this benchmark runs them: token embedding; per layer RMSNorm, grouped-query attention
 with rotary positions (rotate-half convention) and a causal mask, a
 residual add, RMSNorm, a SwiGLU MLP and a residual add; a final RMSNorm
 and the output head over the real vocabulary. No cache, no paging, no
@@ -22,16 +34,107 @@ configurations state.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Sequence
+from typing import Dict, Iterable, List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from bench.lib import weights
+from bench.lib.weights import Leaf
 
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
+
+
+def model_config(cfg: dict):
+    from repro.configs.base import ModelConfig
+    return ModelConfig(
+        name=cfg["name"], family="dense",
+        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"],
+        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
+        head_dim=cfg["head_dim"], rope_theta=float(cfg["rope_theta"]),
+        norm_eps=float(cfg["rms_norm_eps"]),
+        tie_embeddings=bool(cfg["tie_word_embeddings"]),
+        dtype=cfg["torch_dtype"])
+
+
+def layout(cfg: dict) -> List[Leaf]:
+    """Every leaf of the served tree of a dense GQA decoder with SwiGLU
+    and RMSNorm, in a fixed order (the order fixes each leaf's key): one
+    slot a period, so a block leaf is stacked over the layers."""
+    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
+    hd = cfg["head_dim"]
+    hq, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    n = cfg["num_hidden_layers"]
+    vp = weights.padded_vocab(cfg["vocab_size"])
+    blk = ("blocks", "slot00")
+    return [
+        Leaf(("embed", "tok"), (vp, d), 0, 1.0),
+        Leaf(blk + ("mixer", "w_q"), (d, hq * hd), n, d ** -0.5),
+        Leaf(blk + ("mixer", "w_k"), (d, hkv * hd), n, d ** -0.5),
+        Leaf(blk + ("mixer", "w_v"), (d, hkv * hd), n, d ** -0.5),
+        Leaf(blk + ("mixer", "w_o"), (hq * hd, d), n, (hq * hd) ** -0.5),
+        Leaf(blk + ("mixer", "norm"), (d,), n, 0.0),
+        Leaf(blk + ("mlp", "w_gate"), (d, ff), n, d ** -0.5),
+        Leaf(blk + ("mlp", "w_up"), (d, ff), n, d ** -0.5),
+        Leaf(blk + ("mlp", "w_down"), (ff, d), n, ff ** -0.5),
+        Leaf(blk + ("mlp", "norm"), (d,), n, 0.0),
+        Leaf(("final_norm",), (d,), 0, 0.0),
+        Leaf(("lm_head",), (d, vp), 0, d ** -0.5),
+    ]
+
+
+# Counts are of what the algorithm needs, not of what an implementation
+# happens to move: a request's attention reads its own context and nothing
+# past it, and a model FLOP is a multiply-add of a weight the token passes
+# through (two operations), over the real vocabulary.
+
+def matmul_params_per_token(cfg: dict) -> int:
+    """Weights one token multiplies through: every layer's projections
+    and MLP, and the head over the real vocabulary (the embedding is a
+    lookup)."""
+    d, ff, hd = cfg["hidden_size"], cfg["intermediate_size"], cfg["head_dim"]
+    hq, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    per_layer = d * hq * hd + 2 * d * hkv * hd + hq * hd * d + 3 * d * ff
+    return cfg["num_hidden_layers"] * per_layer + d * cfg["vocab_size"]
+
+
+def attention_flops(cfg: dict, kv_len: int) -> int:
+    """QK^T and PV of one query token over ``kv_len`` keys, one layer."""
+    return 4 * kv_len * cfg["num_attention_heads"] * cfg["head_dim"]
+
+
+def decode_step_flops(cfg: dict, kv_lens: Iterable[int]) -> int:
+    """Model FLOPs of one decode step that advances one token for each
+    request, given each request's context length after the step."""
+    dense = 2 * matmul_params_per_token(cfg)
+    layers = cfg["num_hidden_layers"]
+    return sum(dense + layers * attention_flops(cfg, n) for n in kv_lens)
+
+
+def paged_decode_call(cfg: dict, kv_lens: Iterable[int],
+                      itemsize: int = 2) -> tuple:
+    """(operations, bytes) one layer's decode attention needs: for each
+    request, its query and output rows and the K and V rows of its own
+    context, ``kv_len`` of them."""
+    hq, hkv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                   cfg["head_dim"])
+    flops = nbytes = 0
+    for n in kv_lens:
+        flops += attention_flops(cfg, n)
+        nbytes += (2 * n * hkv * hd + 2 * hq * hd) * itemsize
+    return flops, nbytes
+
+
+def paged_decode_work(cfg: dict, kv_lens: Iterable[int]) -> tuple:
+    """(kernel calls, operations, bytes) of one decode step's paged
+    attention: one call per layer, each over every request's context."""
+    layers = cfg["num_hidden_layers"]
+    flops, nbytes = paged_decode_call(cfg, kv_lens)
+    return layers, layers * flops, layers * nbytes
 
 
 def _q8(x):
@@ -96,7 +199,7 @@ def _head(x, rows, tokens, norm, head, *, eps, mode):
     return jnp.max(logits, axis=2) - chosen, jnp.argmax(logits, axis=2)
 
 
-# leaf names of one layer, in ``weights.layout`` order
+# leaf names of one layer, in ``layout`` order
 _LAYER_LEAVES = {"w_q": 1, "w_k": 2, "w_v": 3, "w_o": 4, "attn_norm": 5,
                  "w_gate": 6, "w_up": 7, "w_down": 8, "mlp_norm": 9}
 
@@ -113,6 +216,7 @@ class Reference:
 
     def __init__(self, cfg: dict, seed: int):
         self.cfg = cfg
+        self.leaves = layout(cfg)
         self.key = weights.seed_key(seed)
         self.served = jnp.dtype(cfg["torch_dtype"])
         c = cfg
@@ -130,7 +234,7 @@ class Reference:
                                        [:, :c["vocab_size"]]))
 
     def _leaf(self, index, layer):
-        return weights.layer_leaf(self.key, self.cfg, index, layer,
+        return weights.layer_leaf(self.key, self.leaves, index, layer,
                                   self.served).astype(jnp.float32)
 
     def _layer_weights(self, layer):

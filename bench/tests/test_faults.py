@@ -140,8 +140,11 @@ def test_float8_control_fails_the_limit(seed, monkeypatch):
 
 def test_traced_run_reports_host_layers_without_device_trace():
     """On the CPU there is no device plane: the trace readers find
-    nothing and their metrics are left out, never reported as 0."""
+    nothing and their metrics are left out, never reported as 0. The
+    host clocks and the readers of the program's span ring read."""
     res = tiny_run(trace=True)
-    assert set(res["metrics"]) == {"load_s", "build_s", "consolidate_s"}
+    assert set(res["metrics"]) == {
+        "load_s", "build_s", "consolidate_s", "store_read_s",
+        "first_step_builds", "consolidate_fetch_s", "consolidated_build_s"}
     assert all(np.isfinite(m["value"]) and m["value"] > 0
                for m in res["metrics"].values())

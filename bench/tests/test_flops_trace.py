@@ -1,14 +1,16 @@
-"""The analytic operation and byte counts, the table of peaks, and the
-trace reduction, on hand-made events and on a small recorded trace of a
-TPU v5e (``data/decode_steps.xplane.pb``)."""
+"""The analytic operation and byte counts of the dense architecture, the
+table of peaks, and the trace reduction, on hand-made events and on a
+small recorded trace of a TPU v5e (``data/decode_steps.xplane.pb``)."""
 
 import json
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
-from bench.lib import flops, peaks
+from bench.lib import check, flops, peaks
 from bench.lib import trace as tr
+from bench.references import dense_gqa
 
 ROOT = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).parent / "data"
@@ -23,7 +25,7 @@ def test_matmul_params_per_token_granite():
     c = cfg("granite-3-8b-d12")
     per_layer = 4096 * 4096 + 2 * 4096 * 1024 + 4096 * 4096 \
         + 3 * 4096 * 12800
-    assert flops.matmul_params_per_token(c) == 12 * per_layer + 4096 * 49155
+    assert dense_gqa.matmul_params_per_token(c) == 12 * per_layer + 4096 * 49155
 
 
 def test_internlm2_head_share_of_bytes():
@@ -31,17 +33,17 @@ def test_internlm2_head_share_of_bytes():
     a tenth of granite-3-8b-d12's (both with an untied head)."""
     def head_share(c):
         d, v = c["hidden_size"], c["vocab_size"]
-        return d * v / (flops.matmul_params_per_token(c) + d * v)
+        return d * v / (dense_gqa.matmul_params_per_token(c) + d * v)
     assert 0.19 < head_share(cfg("internlm2-20b-d4")) < 0.23
     assert head_share(cfg("granite-3-8b-d12")) < 0.08
 
 
 def test_paged_decode_call_counts_only_live_context():
     c = cfg("granite-3-8b-d12")
-    f, b = flops.paged_decode_call(c, [100, 1])
+    f, b = dense_gqa.paged_decode_call(c, [100, 1])
     assert f == 4 * 101 * 32 * 128
     assert b == (2 * 101 * 8 * 128 + 2 * 2 * 32 * 128) * 2
-    assert flops.decode_step_flops(c, []) == 0
+    assert dense_gqa.decode_step_flops(c, []) == 0
 
 
 def test_roofline_picks_the_larger_bound():
@@ -90,10 +92,57 @@ def test_recorded_trace_reduces(recorded):
     busy = tr.busy_ns(ev, lo, hi)
     assert busy == pytest.approx(585486248.0)
     assert hi - lo == pytest.approx(682875795.0)
-    # one Pallas kernel (the paged decode attention) per layer per step
-    ns, n = tr.kernel_ns(ev, lo, hi, 'custom_call_target="tpu_custom_call"')
+    # one Pallas kernel (the paged decode attention) per layer per step;
+    # the program then gave its kernels no name and no metadata
+    ns, n = tr.custom_call_ns(ev, lo, hi, "tpu_custom_call")
     assert n == 12 * 32 and ns == pytest.approx(254051730.0)
     assert ns < busy
+    assert tr.kernel_ns(ev, lo, hi, "paged_decode_attention") == (0.0, 0)
+
+
+def test_recorded_roofline_reading_is_unchanged(recorded, traced_lengths):
+    """The kernel reader on the recorded trace, over the lengths of a
+    coldburst cycle's traced steps, reads what it read when it matched a
+    needle over each operation's whole text."""
+    steps = tr.spans(recorded, "bench.step")
+    ev = tr.device_events(recorded)
+    lo, hi = steps[0].start, steps[-1].end
+    run = SimpleNamespace(
+        cfg=cfg("granite-3-8b-d12"), traced_steps=traced_lengths,
+        peak=peaks.peaks("TPU v5 lite"),
+        trace=SimpleNamespace(events=ev, lo=lo, hi=hi,
+                              window_s=(hi - lo) * 1e-9))
+    roofline = check.load_module(
+        ROOT / "bench" / "metrics" / "paged_decode_roofline.py", "roofline")
+    assert roofline.read(run) == 2.0396338452790967
+
+
+def test_kernel_found_by_own_name_or_metadata():
+    """A call is the kernel by its own instruction name or by the
+    ``kernel_metadata`` among its own attributes (as the compiler writes
+    it for v5e); an operation that reads its output names it as an
+    operand and is not a call."""
+    meta = ('%tpu_custom_call.3 = bf16[8,4]{1,0:T(8,128)} custom-call('
+            '%a, %b), custom_call_target="tpu_custom_call", '
+            'frontend_attributes={kernel_metadata={\n"kernel":"k_attn"\n}}')
+    named = ('%k_attn.7 = (bf16[8,4]{1,0}, f32[8]{0}) custom-call(%a), '
+             'custom_call_target="tpu_custom_call"')
+    other = ('%k_attn_b.2 = bf16[8,4]{1,0} custom-call(%a), '
+             'custom_call_target="tpu_custom_call", frontend_attributes='
+             '{kernel_metadata={"kernel":"k_attn_b"}}')
+    readers = ['%bitcast.1 = bf16[32]{0} bitcast(%k_attn.7)',
+               '%fusion.2 = bf16[32]{0} fusion(%tpu_custom_call.3), '
+               'calls=%fused_computation, metadata={op_name="k_attn"}']
+    ops = [tr.Event(str(i), 10 * i, 10 * i + 1 + i, text)
+           for i, text in enumerate([meta, named, other] + readers)]
+    assert tr.kernel_metadata(meta) == {"kernel": "k_attn"}
+    assert tr.kernel_metadata(other) == {"kernel": "k_attn_b"}
+    assert tr.kernel_metadata(readers[0]) == {}
+    assert [tr.op_name(t) for t in (meta, named, readers[1])] == \
+        ["tpu_custom_call", "k_attn", "fusion"]
+    assert tr.kernel_ns(ops, 0, 100, "k_attn") == (3, 2)
+    assert tr.kernel_ns(ops, 0, 100, "k_attn_b") == (3, 1)
+    assert tr.custom_call_ns(ops, 0, 100, "tpu_custom_call") == (6, 3)
 
 
 def test_recorded_breakdown_leaves_out_the_layer_loop(recorded):

@@ -11,6 +11,7 @@ import pytest
 
 from bench.lib import program, weights
 from bench.lib.check import reference_module
+from bench.references import dense_gqa
 
 DATA = Path(__file__).parent / "data"
 
@@ -24,23 +25,26 @@ def tiny(dtype="float32"):
 def test_weights_single_layer_matches_whole_tree():
     cfg = tiny("bfloat16")
     seed = 2 ** 40 + 3
-    tree = weights.make_params(cfg, seed, jnp.bfloat16)
+    leaves = dense_gqa.layout(cfg)
+    tree = weights.make_params(leaves, seed, jnp.bfloat16)
     key = weights.seed_key(seed)
-    for i, leaf in enumerate(weights.layout(cfg)):
+    for i, leaf in enumerate(leaves):
         node = tree
         for k in leaf.path:
             node = node[k]
-        for layer in range(cfg["num_hidden_layers"] if leaf.stacked else 1):
-            one = weights.layer_leaf(key, cfg, i, layer, jnp.bfloat16)
-            want = node[layer] if leaf.stacked else node
+        assert leaf.stack in (0, cfg["num_hidden_layers"])
+        for layer in range(leaf.stack or 1):
+            one = weights.layer_leaf(key, leaves, i, layer, jnp.bfloat16)
+            want = node[layer] if leaf.stack else node
             assert np.array_equal(np.asarray(one), np.asarray(want)), \
                 (leaf.path, layer)
 
 
 def test_weights_depend_on_high_seed_bits():
     cfg = tiny()
-    a = weights.layer_leaf(weights.seed_key(5), cfg, 1, 0, jnp.float32)
-    b = weights.layer_leaf(weights.seed_key(5 + 2 ** 32), cfg, 1, 0,
+    leaves = dense_gqa.layout(cfg)
+    a = weights.layer_leaf(weights.seed_key(5), leaves, 1, 0, jnp.float32)
+    b = weights.layer_leaf(weights.seed_key(5 + 2 ** 32), leaves, 1, 0,
                            jnp.float32)
     assert not np.array_equal(np.asarray(a), np.asarray(b))
     assert abs(float(jnp.std(a)) * 256 ** 0.5 - 1.0) < 0.05
